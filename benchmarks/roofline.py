@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import set_mesh
 from repro.configs import get_config
 from repro.configs.base import RecSysConfig, TransformerConfig
 from repro.configs.specs import cell_spec
@@ -70,7 +69,7 @@ class Cost:
 
 
 def _measure(fn, args_abs, mesh, static_argnums=()) -> Cost:
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn).lower(*args_abs).compile()
     flops, byts = hlo.cost_analysis_terms(compiled)
     coll = hlo.parse_collectives(compiled.as_text())

@@ -44,8 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import lm_head as _lm
+from repro.kernels._common import interpret_mode, pad_to
 
 Array = jax.Array
 
@@ -70,7 +70,8 @@ class HeadSpec:
                         ``softcap=`` kwarg is deprecated).
     ``out_dtype``       output dtype; None = H.dtype.
     ``interpret``       Pallas interpreter toggle; None = auto
-                        (interpret off-TPU, compiled on TPU).
+                        (``kernels._common.interpret_mode``: interpreted
+                        on the CPU, compiled on a TPU).
     ``bwd_batch_chunk`` batch chunking of the pure-JAX backward scan.
     ``unroll``          scan unroll of the pure-JAX impls (cost probes).
     ``rep_topk``        sparsify the (B, V) head output to its top-k
@@ -193,7 +194,7 @@ def _kernel_impl(H, E, b, mask, *, spec: HeadSpec) -> Array:
 
     interpret = spec.interpret
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     # Block resolution happens here, against the shapes this call sees:
     # under shard_map that is the LOCAL vocab shard (V // n_model), so
     # the autotune cache key matches the shard the kernel runs on.
@@ -237,12 +238,11 @@ def make_head(
 
     With a mesh: the backend wrapped in the vocab-sharded shard_map
     body (E/b rows over ``axis_name``, H/Y batch over ``batch_axes``).
-    Vocab divisibility is a property of the *call* (``E.shape[0]``),
-    not the factory, so the returned callable dispatches per call:
-    divisible vocab runs the sharded body; a non-divisible vocab falls
-    back to the unsharded GSPMD-partitionable path — demoting
-    ``impl="kernel"`` to ``"sparton"`` there, because ``pallas_call``
-    has no SPMD partitioning rule outside shard_map.
+    A vocab the model axis does not divide (30522 and 250002 on any
+    axis of size 4) is padded to whole shards with zero rows, whose
+    outputs are 0; that output is gathered over the model axis and the
+    pad sliced off. The selected impl — the Pallas kernel included —
+    always runs inside the sharded body.
     """
     impl_fn = get_head_impl(spec.impl)
 
@@ -254,39 +254,39 @@ def make_head(
 
     n_shard = mesh.shape[axis_name]
 
-    def body(h, e, b_, m_):
-        return impl_fn(h, e, b_, m_, spec=spec)
+    def sharded(gather: bool):
+        def body(h, e, b_, m_):
+            y = impl_fn(h, e, b_, m_, spec=spec)
+            if gather:
+                y = jax.lax.all_gather(y, axis_name, axis=1, tiled=True)
+            return y
 
-    sharded = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(
-            P(batch_axes, None, None),   # H — replicated over model
-            P(axis_name, None),          # E — vocab rows sharded
-            P(axis_name),                # b
-            P(batch_axes, None),         # mask
-        ),
-        out_specs=P(batch_axes, axis_name),
-        check_vma=False,  # custom_vjp inside: skip replication check
-    )
+        return jax.shard_map(
+            body,
+            mesh=mesh,
+            in_specs=(
+                P(batch_axes, None, None),   # H — replicated over model
+                P(axis_name, None),          # E — vocab rows sharded
+                P(axis_name),                # b
+                P(batch_axes, None),         # mask
+            ),
+            out_specs=P(batch_axes, None if gather else axis_name),
+            check_vma=False,  # custom_vjp inside: skip replication check
+        )
 
-    if spec.impl == "kernel":
-        # pallas_call only partitions via shard_map; the unsharded
-        # fallback must stay GSPMD-lowerable under the caller's jit.
-        fallback_spec = spec.replace(impl="sparton")
-        fallback_fn = get_head_impl("sparton")
-    else:
-        fallback_spec, fallback_fn = spec, impl_fn
+    split, gathered = sharded(False), sharded(True)
 
     def head(H, E, b=None, mask=None):
         b, mask = _with_defaults(H, E, b, mask)
-        if E.shape[0] % n_shard == 0:
-            return sharded(H, E, b, mask)
-        warnings.warn(
-            f"make_head: vocab {E.shape[0]} not divisible by "
-            f"{n_shard} {axis_name!r} shards — running the unsharded "
-            f"{fallback_spec.impl!r} head under GSPMD")
-        return fallback_fn(H, E, b, mask, spec=fallback_spec)
+        V = E.shape[0]
+        if V % n_shard == 0:
+            return split(H, E, b, mask)
+        # zero rows give logit 0 -> y = log1p(relu(0)) = 0 and a zero
+        # gradient factor; the padded output is gathered over the model
+        # axis so the pad columns can be sliced off
+        y = gathered(H, pad_to(E, 0, n_shard),
+                     pad_to(b.astype(jnp.float32), 0, n_shard), mask)
+        return y[:, :V]
 
     return head
 

@@ -31,7 +31,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.compat import axis_size, shard_map
 
 Array = jax.Array
 
@@ -95,7 +94,7 @@ def sharded_similarity(
                             preferred_element_type=jnp.float32)
         return jax.lax.psum(scores, axis_name)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(batch_axes, axis_name), P(batch_axes, axis_name)),
@@ -132,7 +131,7 @@ def sharded_infonce(
         # global row offset of this shard's queries
         offset = jnp.zeros((), jnp.int32)
         for ax in batch_axes:  # row-major over batch_axes (gather order)
-            offset = offset * axis_size(ax) + jax.lax.axis_index(ax)
+            offset = offset * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         labels = offset * bq_local + jnp.arange(bq_local)
 
         logp = jax.nn.log_softmax(scores, axis=-1)
@@ -141,7 +140,7 @@ def sharded_infonce(
             local = jax.lax.pmean(local, batch_axes)
         return local
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(batch_axes, axis_name), P(batch_axes, axis_name)),
@@ -165,7 +164,7 @@ def sharded_flops_reg(
         total = jax.lax.psum(local, axis_name)
         return total
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(batch_axes, axis_name),),
@@ -190,7 +189,7 @@ def sharded_l1_reg(
             total = jax.lax.pmean(total, batch_axes)
         return total
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(batch_axes, axis_name),),
@@ -214,7 +213,7 @@ def sharded_row_dots(
                            preferred_element_type=jnp.float32)
         return jax.lax.psum(local, axis_name)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(batch_axes, axis_name), P(batch_axes, axis_name)),

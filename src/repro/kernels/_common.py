@@ -16,6 +16,34 @@ import jax.numpy as jnp
 # in bf16 and lets padded/masked lanes lose every comparison.
 NEG_INF = -1e30
 
+# Scoped VMEM every kernel here is compiled under. Mosaic's default on
+# v5e is 16 MiB; the autotuner's VMEM model budgets against this value
+# (kernels/autotune.py), so the blocks it admits are the blocks that
+# compile.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: on the CPU backend
+    (tests), never on a TPU. Any other backend is an error rather than a
+    silent fall back to the interpreter."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"no Pallas path for backend {backend!r}: kernels compile on a "
+        "TPU and are interpreted on the CPU only")
+
+
+def compiler_params(*dimension_semantics: str):
+    """Mosaic compiler parameters shared by every kernel in the repo."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
 
 def pad_to(x: jax.Array, axis: int, multiple: int, value=0) -> jax.Array:
     """Zero-pad (or ``value``-pad) ``axis`` of ``x`` up to a multiple."""

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels._common import VMEM_LIMIT_BYTES, interpret_mode
+
 Blocks = Tuple[int, int, int]  # (block_b, block_s, block_v)
 ImpactBlocks = Tuple[int, int]  # (block_n, block_w)
 
@@ -51,15 +53,26 @@ KERNELS = ("fwd", "dh", "de")
 IMPACT_VARIANTS = ("f32", "u4")
 
 CACHE_ENV = "SPARTON_AUTOTUNE_CACHE"
-DEFAULT_CACHE = os.path.join(
-    os.path.expanduser("~"), ".cache", "sparton", "autotune.json"
-)
-# ~16 MB VMEM per TensorCore; leave headroom for Mosaic's own buffers.
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# Inside the checkout, never under $HOME: the blocks a run uses must not
+# depend on state left outside the repository.
+DEFAULT_CACHE = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".autotune", "autotune.json"))
+# The model below over-counts what Mosaic allocates, so budgeting it
+# against the limit the kernels are compiled under keeps every admitted
+# candidate compilable.
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES
 
-_BB_CHOICES = (1, 2, 4, 8, 16, 32)
-_BS_CHOICES = (64, 128, 256, 512)
+# Mosaic tiling: a block's last two dims are multiples of (8, 128) or
+# the whole array dim. block_b sits on the sublanes of the (B, V)
+# tiles, block_s on the lanes of the (B, S) mask, block_v on the lanes
+# of the (B, V) tiles.
+_BB_CHOICES = (8, 16, 32)
+_BS_CHOICES = (128, 256, 512)
 _BV_CHOICES = (128, 256, 512, 1024, 2048)
+# block_s may instead be the whole sequence, padded to the bf16
+# sublane tile, so short sequences need not pad to 128.
+_S_ALIGN = 16
 _IMPACT_BN_CHOICES = (128, 256, 512, 1024, 2048, 4096)
 _IMPACT_BW_CHOICES = (128, 256, 512)
 
@@ -236,10 +249,11 @@ def candidate_blocks(
 ) -> List[Blocks]:
     """All (block_b, block_s, block_v) under the VMEM budget, best first.
 
-    Candidates keep the MXU/VPU alignment rules (block_v a multiple of
-    the 128 lane width; block_s a multiple of the sublane tile) and skip
-    blocks grossly larger than the padded problem. Sorted by the
-    analytic HBM-traffic model, least traffic first. ``pinned``
+    Candidates keep Mosaic's tiling rules (block_b a multiple of 8;
+    block_s a multiple of 128 or the whole padded sequence; block_v a
+    multiple of 128) and skip blocks grossly larger than the padded
+    problem. Sorted by the analytic HBM-traffic model, least traffic
+    first. ``pinned``
     components (from a config) are honored exactly — only the free
     components are enumerated, and the VMEM budget is checked on the
     *combined* triple. ``kernel`` scopes both the VMEM residency and
@@ -247,16 +261,17 @@ def candidate_blocks(
     joint behavior (worst-case VMEM, forward traffic).
     """
     pb, ps, pv = pinned or (None, None, None)
+    whole_s = -(-S // _S_ALIGN) * _S_ALIGN
     bbs = (pb,) if pb is not None else _BB_CHOICES
-    bss = (ps,) if ps is not None else _BS_CHOICES
+    bss = (ps,) if ps is not None else tuple(sorted(
+        {bs for bs in _BS_CHOICES if bs <= max(128, 2 * S)}
+        | ({whole_s} if whole_s <= max(_BS_CHOICES) else set())))
     bvs = (pv,) if pv is not None else _BV_CHOICES
     out = []
     for bb in bbs:
         if pb is None and bb > max(8, B):
             continue
         for bs in bss:
-            if ps is None and bs > max(128, 2 * S):
-                continue
             for bv in bvs:
                 if pv is None and bv > max(128, 2 * V):
                     continue
@@ -367,7 +382,7 @@ def autotune_blocks(
 
     backend = backend or jax.default_backend()
     if interpret is None:
-        interpret = backend != "tpu"
+        interpret = interpret_mode()
     p = cache_path(path)
     cache = _load(p)
     key = shape_key(B, S, D, V, dtype, backend)
@@ -470,7 +485,7 @@ def autotune_kernel_blocks(
 
     backend = backend or jax.default_backend()
     if interpret is None:
-        interpret = backend != "tpu"
+        interpret = interpret_mode()
     p = cache_path(path)
     cache = _load(p)
     keys = {kn: shape_key(B, S, D, V, dtype, backend, kn)
@@ -622,24 +637,23 @@ def impact_vmem_bytes(blocks: ImpactBlocks, Q: int, L: int,
 
     The posting window stays resident across every doc tile of a query
     (same block index -> no re-fetch, but Pallas still double-buffers
-    it), the per-chunk one-hot tile lives in registers/VMEM during the
-    contraction, and the running top-k merge needs the union working
-    set. Per variant: "f32" ships two (1, W) arrays (f32 weights + i32
-    docs); "u4" ships two (1, Q, L) i32 windows plus five (1, Q, 1)
-    per-term columns and decodes (Q, L) weight/doc planes in-kernel.
+    it); each chunk builds a (block_n, block_w) one-hot from an iota of
+    the same shape and a compare; the running top-k selection carries a
+    few (1, block_n) rows. Per variant: "f32" ships two (W/bw, bw)
+    windows (f32 weights + i32 docs); "u4" ships two (Q, L) i32
+    windows plus five (Q, 1) per-term columns, padded to 128 lanes, and
+    decodes into two (Q, L) scratch planes, with decode temporaries.
     """
     bn, bw = blocks
     f32 = 4
     w_lanes = Q * max(L, 1)
-    if variant == "f32":
-        resident = 2 * 2 * w_lanes * f32           # w + docs, dbl-buf
-    else:
-        resident = (2 * 2 * w_lanes * f32          # byte + gap windows
-                    + 2 * 5 * Q * f32              # per-term columns
-                    + 2 * w_lanes * f32)           # decoded w + docs
-    onehot = bw * bn * f32                         # chunk one-hot tile
-    merge = 4 * bn * f32                           # union vals+ids, x2
-    return resident + onehot + bn * f32 + merge
+    resident = 2 * 2 * w_lanes * f32               # two windows, dbl-buf
+    if variant != "f32":
+        resident += (2 * 5 * Q * 128 * f32         # per-term columns
+                     + 8 * w_lanes * f32)          # decoded planes + temps
+    onehot = 3 * bw * bn * f32                     # one-hot, iota, compare
+    merge = 6 * bn * f32                           # selection carries
+    return resident + onehot + merge
 
 
 def impact_traffic_proxy(blocks: ImpactBlocks, B: int, Q: int, L: int,
@@ -765,7 +779,7 @@ def autotune_impact_blocks(
 
     backend = backend or jax.default_backend()
     if interpret is None:
-        interpret = backend != "tpu"
+        interpret = interpret_mode()
     p = cache_path(path)
     cache = _load(p)
     key = impact_shape_key(B, Q, L, N, variant, backend)

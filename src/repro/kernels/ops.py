@@ -15,10 +15,11 @@ entry and heuristic), else the analytic heuristic
 (``repro.kernels.autotune``). Passing ints pins the same triple across
 all three kernels (the legacy joint behavior).
 
-On this CPU container the kernels run with ``interpret=True`` (the
-kernel body executed by the Pallas interpreter); on TPU the same code
+On the CPU backend the kernels run with ``interpret=True`` (the kernel
+body executed by the Pallas interpreter); on a TPU the same code
 compiles to Mosaic. ``interpret`` is threaded through as a static
-argument so tests/benchmarks choose explicitly.
+argument; ``head_api`` resolves its default from the backend
+(``_common.interpret_mode``).
 """
 
 from __future__ import annotations

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._common import NEG_INF, pad_to
+from repro.kernels._common import NEG_INF, compiler_params, pad_to
 
 
 def _fwd_kernel(
@@ -83,10 +83,12 @@ def _fwd_kernel(
     logits = logits + bias_ref[...]  # (1, bv) broadcasts over rows
     if softcap is not None:
         logits = softcap * jnp.tanh(logits / softcap)
-    logits = logits.reshape(bb, bs, bv)
-
-    keep = mask_ref[...] > 0  # (bb, bs)
-    logits = jnp.where(keep[:, :, None], logits, NEG_INF)
+    # The mask arrives with s on lanes; the logit rows carry s on
+    # sublanes. Mosaic cannot reshape (bb, bs) -> (bb*bs, 1) directly,
+    # so transpose it and stack its columns into one row mask.
+    mask_t = mask_ref[...].T  # (bs, bb)
+    keep = jnp.concatenate([mask_t[:, r:r + 1] for r in range(bb)], axis=0)
+    logits = jnp.where(keep > 0, logits, NEG_INF).reshape(bb, bs, bv)
 
     tile_max = jnp.max(logits, axis=1)  # (bb, bv)
     # First-occurrence argmax without lax.argmax (portable in Pallas):
@@ -154,9 +156,7 @@ def _forward_call(
             pltpu.VMEM((block_b, block_v), jnp.float32),
             pltpu.VMEM((block_b, block_v), jnp.int32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(Hp, Ep, bp, maskp)
     return y[:B, :V], i_max[:B, :V]

@@ -51,7 +51,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._common import bwd_factor, onehot_weights, pad_to
+from repro.kernels._common import (bwd_factor, compiler_params,
+                                   onehot_weights, pad_to)
 
 
 def _dh_kernel(
@@ -183,9 +184,7 @@ def _dh_call(
         scratch_shapes=[
             pltpu.VMEM((block_b, block_s, D), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(dyp, yp, ip, Ep)
     return dH[:B, :seq_len]
@@ -236,9 +235,8 @@ def _de_call(
             pltpu.VMEM((block_v, D), jnp.float32),
             pltpu.VMEM((1, block_v), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=compiler_params("parallel", "arbitrary",
+                                        "arbitrary"),
         interpret=interpret,
     )(dyp, yp, ip, Hp)
     return dE[:V], db[0, :V]

@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import set_mesh
-
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import (DimeNetConfig, RecSysConfig,
                                 TransformerConfig)
@@ -200,7 +198,7 @@ def run_cell(arch_id: str, shape_name: str, mesh,
         for k, v in cell.batch.items()
     }
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if needs_state:
             # donate the train state: params/opt update in place
             jitted = jax.jit(step, donate_argnums=(0,),

@@ -59,6 +59,12 @@ def main(argv=None) -> int:
                          "sparsifier; 0 = dense reps (legacy path)")
     ap.add_argument("--method", default="auto", choices=list(METHODS),
                     help="retrieval path (see repro.retrieval.retrieve)")
+    ap.add_argument("--check-method", default=None, choices=list(METHODS),
+                    help="also retrieve through this method on the same "
+                         "index and exit non-zero unless its top-k ids "
+                         "equal --method's")
+    ap.add_argument("--full", action="store_true",
+                    help="use the full (published) config, not SMOKE")
     ap.add_argument("--shards", type=int, default=2,
                     help="--method sharded/term_sharded: shard count "
                          "(single-device vmap path unless a mesh is "
@@ -155,12 +161,19 @@ def main(argv=None) -> int:
                  "tenant corpora live on the IndexBuilder)")
     if args.tenants < 0:
         ap.error("--tenants must be >= 0")
+    if args.check_method and args.engine:
+        ap.error("--check-method compares two methods on one frozen "
+                 "index; drop --engine")
 
     import dataclasses
 
     import jax
     import jax.numpy as jnp
     import numpy as np
+
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
 
     from repro.configs import get_config
     from repro.launch.steps import init_state
@@ -171,7 +184,7 @@ def main(argv=None) -> int:
                                        make_config_encoder)
 
     mod = get_config(args.arch)
-    cfg = mod.SMOKE
+    cfg = mod.CONFIG if args.full else mod.SMOKE
     overrides = {}
     if args.head_impl:
         overrides["head_impl"] = args.head_impl
@@ -180,7 +193,8 @@ def main(argv=None) -> int:
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
     sparse = args.rep_topk > 0
-    state, _ = init_state(args.arch, jax.random.PRNGKey(0), smoke=True)
+    state, _ = init_state(args.arch, jax.random.PRNGKey(0),
+                          smoke=not args.full)
     params = state["params"]
 
     # Built from the config via the unified head factory: head_impl,
@@ -443,17 +457,23 @@ def main(argv=None) -> int:
           f"failed), batches: {list(loop.batch_sizes)}, "
           f"occupancy {st['batch_occupancy']:.2f}, "
           f"p99 {st['p99_latency_s'] * 1e3:.1f} ms")
+    if st["failed"]:
+        # an encode that raised (a kernel that does not compile, a lost
+        # device) fails the run; shedding under a deadline does not
+        print(f"FAILED: {st['failed']} requests failed to encode",
+              file=sys.stderr)
+        return 1
     if not results:
         print("every request shed — deadline too tight for this "
               "host; nothing to retrieve")
         return 0
 
     # --- 3. retrieval through the unified dispatcher ------------------
-    n_q = min(8, len(results))
+    n_q = len(results)
     if sparse:
-        queries = stack_rows(results[:n_q])
+        queries = stack_rows(results)
     else:
-        queries = jnp.asarray(np.stack(results[:n_q]))
+        queries = jnp.asarray(np.stack(results))
     t0 = time.monotonic()
     if engine is not None:
         kw = {}
@@ -479,7 +499,15 @@ def main(argv=None) -> int:
     jax.block_until_ready(vals)
     print(f"retrieval[{tag}]: top-{args.topk} for {n_q} queries "
           f"in {(time.monotonic() - t0) * 1e3:.1f} ms, "
-          f"best scores {np.asarray(vals)[:, 0].round(2).tolist()}")
+          f"best scores {np.asarray(vals)[:8, 0].round(2).tolist()}")
+    if args.check_method:
+        _, ref_idx = retrieve(queries, corpus, args.topk,
+                              method=args.check_method)
+        equal = bool(np.array_equal(np.asarray(idx), np.asarray(ref_idx)))
+        print(f"retrieval[{tag}] ids equal to "
+              f"retrieval[{args.check_method}]: {equal}")
+        if not equal:
+            return 1
     if cached is not None:
         cs = cached.stats()
         rc, hot = cs["results"], cs.get("hot")

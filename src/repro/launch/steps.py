@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import pcast
 from repro.configs import get_config
 from repro.configs.base import (DimeNetConfig, RecSysConfig,
                                 TransformerConfig)
@@ -71,8 +70,8 @@ def _encode_fn(cfg: TransformerConfig, mesh: Optional[Mesh],
     The head — any registered backend, Pallas kernel included — comes
     from the unified factory: ``make_head`` puts the selected impl
     inside the vocab-sharded shard_map body when a mesh is given (with
-    kernel blocks resolved per *local* vocab shard) and handles the
-    non-divisible-vocab fallback itself.
+    kernel blocks resolved per *local* vocab shard) and pads a vocab
+    the model axis does not divide.
     """
     moe_shard = _moe_shard(cfg, mesh)
     layer_unroll = cfg.n_layers if unroll else 1
@@ -316,7 +315,7 @@ def streaming_topk(q: Array, C: Array, *, k: int,
             jnp.zeros((B, k), jnp.int32))
     if vary_axes:
         init = jax.tree.map(
-            lambda x: pcast(x, vary_axes, to="varying"), init)
+            lambda x: jax.lax.pcast(x, vary_axes, to="varying"), init)
     (vals, idx), _ = jax.lax.scan(
         body, init, (C_t, jnp.arange(n_tiles, dtype=jnp.int32)))
     return vals, idx
@@ -353,9 +352,7 @@ def build_retrieval_step(cfg: RecSysConfig, mesh: Optional[Mesh],
         v2, pos = jax.lax.top_k(all_v, k)
         i2 = jnp.take_along_axis(all_i, pos, axis=1)
         return v2, i2
-
-    from repro.compat import shard_map
-    merged = shard_map(
+    merged = jax.shard_map(
         sharded_body, mesh=mesh,
         in_specs=(P(), P(axes, None)),
         out_specs=(P(), P()),

@@ -4,17 +4,21 @@ Wires configs -> data pipeline -> jitted train step (with shardings
 when devices allow a mesh) -> fault-tolerant runner (checkpoint/
 restart, straggler policy, elastic re-mesh).
 
-On the CPU container this runs the SMOKE config end-to-end (the
-assigned full configs are exercised by the dry-run); on a real pod the
-same driver takes ``--full`` and the production mesh.
+By default this runs the SMOKE config; ``--full`` takes the published
+config (e.g. ``splade_bert`` at 12L, d=768, V=30522). On the CPU the
+Pallas head runs in the interpreter, on a TPU it is compiled. The run
+exits non-zero when a step was skipped or the last loss is not finite;
+a failure of the first step (where the step compiles) is raised.
 
 XLA flags for collective overlap (latency-hiding scheduler) are set
 before jax initializes when --overlap is passed.
 """
 
 import argparse
+import math
 import os
 import sys
+import tempfile
 
 
 def _set_overlap_flags() -> None:
@@ -35,7 +39,9 @@ def main(argv=None) -> int:
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=32)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(),
+                                         "repro_ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--full", action="store_true",
                     help="use the full (assigned) config, not SMOKE")
@@ -76,7 +82,10 @@ def main(argv=None) -> int:
 
     import jax
     import jax.numpy as jnp
-    import numpy as np
+
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
 
     from repro.configs import get_config
     from repro.configs.base import (DimeNetConfig, RecSysConfig,
@@ -207,7 +216,7 @@ def main(argv=None) -> int:
         jitted, state, iter(loader),
         config=RunnerConfig(ckpt_dir=args.ckpt_dir,
                             ckpt_every=args.ckpt_every,
-                            max_steps=args.steps),
+                            max_steps=args.steps, log_every=1),
         place_batch=place,
         on_step=eval_hook,
     )
@@ -221,8 +230,8 @@ def main(argv=None) -> int:
     loss_entries = [m for m in runner.metrics_log if "loss" in m]
     if loss_entries:
         print(f"step {loss_entries[-1]['step']}: "
-              f"loss {float(loss_entries[-1]['loss']):.4f} "
-              f"(first {float(loss_entries[0]['loss']):.4f})")
+              f"loss {float(loss_entries[-1]['loss']):.6g} "
+              f"(first {float(loss_entries[0]['loss']):.6g})")
     if init_metrics and eval_log:
         final = eval_log[-1][1]
         print("eval improvement over init: " + " ".join(
@@ -232,6 +241,12 @@ def main(argv=None) -> int:
           f"{len(runner.skipped_steps)} skipped, "
           f"{len(runner.remesh_events)} re-mesh events")
     loader.close()
+    # a skipped step or a diverged loss is a failed run, not a warning
+    last_loss = float(loss_entries[-1]["loss"]) if loss_entries else 0.0
+    if runner.skipped_steps or not math.isfinite(last_loss):
+        print(f"FAILED: skipped steps {runner.skipped_steps}, "
+              f"last loss {last_loss}", file=sys.stderr)
+        return 1
     return 0
 
 

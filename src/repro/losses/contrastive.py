@@ -55,14 +55,13 @@ def gathered_infonce(
     """
     if not axis_names:
         return infonce_loss(q_reps, d_reps, temperature=temperature)
-    from repro.compat import axis_size
 
     d_full = jax.lax.all_gather(d_reps, axis_names, axis=0, tiled=True)
     scores = jnp.einsum("qv,dv->qd", q_reps, d_full,
                         preferred_element_type=jnp.float32) / temperature
     offset = jnp.zeros((), jnp.int32)
     for ax in axis_names:
-        offset = offset * axis_size(ax) + jax.lax.axis_index(ax)
+        offset = offset * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     labels = offset * q_reps.shape[0] + jnp.arange(q_reps.shape[0])
     logp = jax.nn.log_softmax(scores, axis=-1)
     local = -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))

@@ -175,9 +175,8 @@ def forward_dense_triplets(
             # wire_dtype=bf16 halves a2a wire+buffers on TPU, but the
             # CPU backend legalizes bf16 back to f32 (measured: no
             # delta, +converts) -> off by default in the dry-run
-            from repro.compat import shard_map
             flat = idx.reshape(-1)
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda t, i: distributed_take_local(
                     t, i, axis_names=shard_axes)[0],
                 mesh=None,
@@ -189,9 +188,8 @@ def forward_dense_triplets(
             return out.reshape(idx.shape + (table.shape[-1],))
 
         def scatter_rows(vals, idx, n_rows, wire_dtype=None):
-            from repro.compat import shard_map
             # rows per shard must divide; specs pad to 512
-            fn = shard_map(
+            fn = jax.shard_map(
                 lambda v, i: distributed_segment_sum_local(
                     v, i, n_rows // _n_shards(shard_axes),
                     axis_names=shard_axes)[0],

@@ -45,13 +45,12 @@ def _apply_moe(x2d: Array, mlp: Params, cfg: TransformerConfig,
             x2d, mlp["router"], mlp["w_gate"], mlp["w_up"], mlp["w_down"],
             top_k=cfg.top_k, capacity_factor=cfg.capacity_factor)
     token_axes, expert_axis = moe_shard
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
     body = functools.partial(
         moe_ffn_local_experts,
         top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
         expert_axis=expert_axis, token_axes=token_axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=None,
         in_specs=(P(token_axes, None), P(None, None),
                   P(expert_axis, None, None), P(expert_axis, None, None),

@@ -19,8 +19,6 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 PyTree = Any
 
 
@@ -101,7 +99,7 @@ def compressed_allreduce(
     ``residual`` is the flat fp32 error-feedback buffer (None at step
     0). Returns (mean grads pytree, new residual).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     flat, spec = _flatten(grads)
     size = flat.shape[0]
     pad = (-size) % n

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels._common import interpret_mode
 from repro.retrieval.index import InvertedIndex
 from repro.retrieval.sparse_rep import SparseRep
 from repro.sparse.segment import segment_sum
@@ -290,13 +291,13 @@ def fused_quantized_retrieve(
 
     None blocks resolve through the autotune cache/heuristic under the
     ``u4`` ``_impact`` keys; ``interpret`` defaults to the Pallas
-    interpreter off-TPU.
+    interpreter on the CPU (``kernels._common.interpret_mode``).
     """
     from repro.kernels.autotune import resolve_impact_blocks
     from repro.kernels.impact_score import fused_quantized_topk
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     b = queries.values.reshape(-1, queries.width).shape[0]
     block_n, block_w = resolve_impact_blocks(
         b, queries.width, index.max_postings, index.n_docs,

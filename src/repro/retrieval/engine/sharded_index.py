@@ -91,7 +91,7 @@ def resolve_shard_axis(mesh, axis_name: Optional[str], n_shards: int,
 
 def shard_mapped(body, mesh, axis_name: Optional[str], n_in: int,
                  n_out: int = 2, in_specs=None):
-    """``compat.shard_map`` wrapper shared by the sharded indexes:
+    """``jax.shard_map`` wrapper shared by the sharded indexes:
     the first ``n_in`` args are split on ``axis_name`` (one shard per
     device), outputs are replicated. The 2D grid passes explicit
     ``in_specs`` instead (its stacked arrays split on two mesh axes at
@@ -100,8 +100,6 @@ def shard_mapped(body, mesh, axis_name: Optional[str], n_in: int,
     but the vma/rep tracer cannot prove it, same situation as
     ``build_retrieval_step``."""
     from jax.sharding import PartitionSpec as P
-
-    from repro.compat import shard_map
 
     if in_specs is None:
         in_specs = tuple(P(axis_name) for _ in range(n_in))
@@ -112,7 +110,7 @@ def shard_mapped(body, mesh, axis_name: Optional[str], n_in: int,
                 f"shard_mapped: {len(in_specs)} in_specs for "
                 f"{n_in} inputs")
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=tuple(P() for _ in range(n_out)),

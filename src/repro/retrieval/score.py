@@ -119,6 +119,7 @@ from typing import Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from repro.kernels._common import interpret_mode
 from repro.kernels.topk_score import topk_score
 from repro.retrieval.index import InvertedIndex
 from repro.retrieval.sparse_rep import SparseRep
@@ -262,13 +263,13 @@ def fused_retrieve(
 
     None blocks resolve through the autotune cache/heuristic
     (``_impact`` keys); ``interpret`` defaults to the Pallas
-    interpreter off-TPU.
+    interpreter on the CPU (``kernels._common.interpret_mode``).
     """
     from repro.kernels.autotune import resolve_impact_blocks
     from repro.kernels.impact_score import fused_impact_topk
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     b = queries.values.reshape(-1, queries.width).shape[0]
     block_n, block_w = resolve_impact_blocks(
         b, queries.width, index.max_postings, index.n_docs,
@@ -378,7 +379,7 @@ def retrieve(
     ``(B, min(k, N))`` shape. Tuning kwargs are validated against the
     *resolved* method (``_METHOD_KWARGS``) — a kwarg the method cannot
     honor raises instead of being ignored. ``interpret`` affects the
-    Pallas-backed paths (None = auto: interpreter off-TPU);
+    Pallas-backed paths (None = auto: interpreter on the CPU only);
     ``block_b``/``block_n`` tune the streaming kernel and
     ``block_n``/``block_w`` the fused one (None = autotune cache /
     heuristic); ``prune_margin``/``candidates`` drive the pruned path
@@ -486,7 +487,7 @@ def retrieve(
         return _dense_retrieve(q, corpus, k)
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     return topk_score(q, corpus, k=k,
                       block_b=block_b if block_b is not None else 8,
                       block_n=block_n if block_n is not None else 1024,

@@ -7,6 +7,10 @@ Designed for 1000+ nodes where *something* is always failing:
   (repro.checkpoint); on start the runner auto-resumes from the latest
   valid step. A SIGTERM-style shutdown hook flushes a final
   checkpoint.
+* **Failed steps** — an exception from a step is treated as a lost
+  device: the step is skipped, like a straggler. The exception is that
+  of the first step a run attempts, which is where the step compiles:
+  it is raised, so a kernel that cannot compile stops the run.
 * **Straggler mitigation** — every step runs under a deadline
   (EWMA of recent step times × slack). A step exceeding the deadline
   is retried once; a second miss marks the step skipped (the grad
@@ -151,6 +155,7 @@ class FaultTolerantRunner:
         self._ckpt = AsyncCheckpointer(config.ckpt_dir,
                                        keep=config.keep_ckpts)
         self.start_step = 0
+        self._attempted = False
         self.suspect_strikes = 0
         self.skipped_steps: List[int] = []
         self.remesh_events: List[int] = []
@@ -201,12 +206,19 @@ class FaultTolerantRunner:
     def _attempt_step(self, placed_batch, step: int
                       ) -> Tuple[bool, Dict[str, Any]]:
         deadline = self._step_clock.deadline()
+        first = not self._attempted
+        self._attempted = True
         for _ in range(1 + self.config.straggler.max_retries):
             t0 = self.clock()
             try:
                 new_state, metrics = self.step_fn(self.state, placed_batch)
                 new_state = jax.block_until_ready(new_state)
             except Exception as e:  # device loss surfaces as XlaRuntimeError
+                # The first step compiles; a lost device and a compile
+                # error are both RuntimeErrors, so a failure there is
+                # raised rather than skipped.
+                if first:
+                    raise
                 return False, {"error": repr(e)}
             dt = self.clock() - t0
             if dt <= deadline:

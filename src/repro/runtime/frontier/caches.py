@@ -50,9 +50,9 @@ import dataclasses
 import hashlib
 from typing import Any, Dict, List, Mapping, Optional, Tuple
 
-import jax
 import numpy as np
 
+from repro.kernels._common import interpret_mode
 from repro.retrieval.index import InvertedIndex
 from repro.retrieval.sparse_rep import SparseRep, split_rows, stack_rows
 
@@ -310,7 +310,7 @@ def hot_fused_retrieve(
     from repro.kernels.impact_score import fused_impact_topk
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     qv = np.asarray(queries.values, np.float32).reshape(
         -1, queries.width)
     qi = np.asarray(queries.indices, np.int32).reshape(

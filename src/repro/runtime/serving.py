@@ -71,6 +71,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import enum
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -102,12 +103,16 @@ def make_config_encoder(params: Any, cfg: Any, *, spec: Any = None,
     enc = make_encoder(spec if spec is not None else cfg.head_spec(),
                        mesh=mesh)
 
-    def encode(tokens: Array, mask: Array):
+    def encode(params, tokens: Array, mask: Array):
         Hs, _ = tfm.forward_hidden(params, cfg, tokens, mask)
         E, b = tfm.head_weights(params, cfg)
         return enc(Hs, E.astype(Hs.dtype), b, mask)
 
-    return jax.jit(encode) if jit else encode
+    # params are an argument of the jitted program, not a closure: a
+    # closed-over model is baked into the executable as constants
+    # (about 1 GB at splade_bert's full width)
+    fn = jax.jit(encode) if jit else encode
+    return functools.partial(fn, params)
 
 
 @dataclasses.dataclass

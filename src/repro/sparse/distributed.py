@@ -31,8 +31,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 Array = jax.Array
 
 
@@ -58,7 +56,7 @@ def distributed_take_local(
     R = idx_local.shape[0]
     n = 1
     for ax in axis_names:
-        n *= axis_size(ax)
+        n *= jax.lax.axis_size(ax)
     C = _capacity(R, n, cap_factor)
 
     owner = jnp.clip(idx_local // rows_local, 0, n - 1)       # (R,)
@@ -110,7 +108,7 @@ def distributed_segment_sum_local(
     R, d = vals_local.shape
     n = 1
     for ax in axis_names:
-        n *= axis_size(ax)
+        n *= jax.lax.axis_size(ax)
     C = _capacity(R, n, cap_factor)
 
     owner = jnp.clip(idx_local // out_local_rows, 0, n - 1)
@@ -148,13 +146,12 @@ def make_distributed_take(mesh, axis_names: Tuple[str, ...],
                           *, cap_factor: float = 1.25):
     """Factory: take(src, idx) -> (rows, dropped) with src row-sharded
     and idx row-sharded over ``axis_names``."""
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     body = functools.partial(distributed_take_local,
                              axis_names=axis_names,
                              cap_factor=cap_factor)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis_names, None), P(axis_names)),
         out_specs=(P(axis_names, None), P()),

@@ -20,7 +20,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.compat import shard_map
 
 Array = jax.Array
 
@@ -51,7 +50,7 @@ def make_sharded_lookup(mesh: Mesh, axis_name: str = "model"):
     """
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis_name, None), P()),
         out_specs=P(),

@@ -27,12 +27,20 @@ def isolated_cache(tmp_path, monkeypatch):
 
 
 def test_candidates_respect_vmem_budget():
-    budget = 4 * 1024 * 1024
+    budget = 12 * 1024 * 1024
     cands = candidate_blocks(320, 512, 768, 30522, vmem_budget=budget)
-    assert cands, "no candidates under a 4 MiB budget at bert-base size"
+    assert cands, "no candidates under a 12 MiB budget at bert-base size"
     for blocks in cands:
         assert vmem_bytes(blocks, 768) <= budget
-        assert blocks[2] % 128 == 0  # lane alignment preserved
+        # Mosaic's (8, 128) tiling: block_b on sublanes, block_s and
+        # block_v on lanes
+        assert blocks[0] % 8 == 0
+        assert blocks[1] % 128 == 0
+        assert blocks[2] % 128 == 0
+    # a short sequence may take its whole padded length as one block
+    short = candidate_blocks(4, 40, 64, 1000)
+    assert {bs for _, bs, _ in short} <= {48, 128}
+    assert any(bs == 48 for _, bs, _ in short)
 
 
 def test_candidates_sorted_by_traffic_model():
@@ -59,6 +67,13 @@ def test_heuristic_fallback_when_budget_unreachable():
     # larger "default" that would amplify the VMEM overflow
     assert heuristic_blocks(8, 128, 65536, 1024,
                             vmem_budget=1) == MIN_BLOCKS
+
+
+def test_default_cache_lives_in_the_checkout():
+    """Without SPARTON_AUTOTUNE_CACHE the winners file sits inside the
+    checkout, so no state outside the repository changes the blocks."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert os.path.commonpath([autotune.DEFAULT_CACHE, repo]) == repo
 
 
 def test_get_blocks_without_cache_is_heuristic():
@@ -116,7 +131,7 @@ def test_partial_pin_respects_vmem_budget():
     # the dE scratch alone): minimal free components, not silent drop
     blocks = heuristic_blocks(320, 512, 768, 250002,
                               pinned=(None, None, 2048))
-    assert blocks == (1, 64, 2048)
+    assert blocks == MIN_BLOCKS[:2] + (2048,)
     # the kernel-wrapper path must re-enumerate jointly too, not graft
     # the pin onto the unpinned winner
     blocks = resolve_blocks(64, 512, 64, 250002, jnp.float32,

@@ -165,6 +165,24 @@ def test_lm_head_softcap_kwarg_deprecated():
         atol=0)
 
 
+def test_kernel_interpret_default_follows_backend(monkeypatch):
+    """interpret=None: interpreted on the CPU, compiled on a TPU, and an
+    error on any other backend — never a silent interpreter fallback."""
+    from repro.kernels import _common
+
+    H, E, b, mask = _inputs()
+    auto = make_head(_spec("kernel").replace(interpret=None))
+    np.testing.assert_allclose(np.asarray(auto(H, E, b, mask)),
+                               np.asarray(lm_head_naive(H, E, b, mask)),
+                               atol=1e-5, rtol=1e-5)
+    assert _common.interpret_mode() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _common.interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        _common.interpret_mode()
+
+
 # ---------------------------------------------------------------------------
 # config -> spec
 # ---------------------------------------------------------------------------
@@ -197,7 +215,6 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     os.environ["SPARTON_AUTOTUNE_CACHE"] = os.path.join(
         os.environ.get("TMPDIR", "/tmp"), "sparton_headapi_test.json")
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import set_mesh
     from repro.core.head_api import HeadSpec, make_head
     from repro.core.lm_head import lm_head_naive
     import repro.kernels.autotune as autotune
@@ -221,7 +238,12 @@ _SHARDED_SCRIPT = textwrap.dedent("""
         return _orig(B_, S_, D_, V_, dtype, bb, bs, bv, **kw)
     autotune.resolve_blocks = _spy
 
-    for n_model, softcap in [(1, None), (2, None), (2, 4.0)]:
+    E_all, b_all = E, b
+    # V=63 on two shards: the vocab is padded to whole shards, never
+    # demoted to the pure-JAX head
+    for n_model, softcap, V in [(1, None, 64), (2, None, 64),
+                                (2, 4.0, 64), (2, None, 63)]:
+        E, b = E_all[:V], b_all[:V]
         mesh = jax.make_mesh(
             (n_model,), ("model",),
             devices=jax.devices()[:n_model])
@@ -234,10 +256,10 @@ _SHARDED_SCRIPT = textwrap.dedent("""
         head_s = make_head(spec_s, mesh=mesh, batch_axes=())
 
         seen_V.clear()
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_k = jax.jit(head_k)(H, E, b, mask)
             y_s = jax.jit(head_s)(H, E, b, mask)
-        assert seen_V and all(v == V // n_model for v in seen_V), \\
+        assert seen_V and all(v == -(-V // n_model) for v in seen_V), \\
             (n_model, seen_V)
         np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_ref),
                                    atol=1e-5, rtol=1e-5)
@@ -246,7 +268,7 @@ _SHARDED_SCRIPT = textwrap.dedent("""
 
         def loss(fn):
             return lambda H, E, b: jnp.sum(jnp.sin(fn(H, E, b, mask)))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             g_k = jax.jit(jax.grad(loss(head_k), (0, 1, 2)))(H, E, b)
             g_s = jax.jit(jax.grad(loss(head_s), (0, 1, 2)))(H, E, b)
         g_ref = jax.grad(
@@ -258,7 +280,8 @@ _SHARDED_SCRIPT = textwrap.dedent("""
         for a, c in zip(g_k, g_s):
             np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                        atol=1e-4, rtol=1e-4)
-        print(f"OK sharded kernel n_model={n_model} softcap={softcap}")
+        print(f"OK sharded kernel n_model={n_model} softcap={softcap} "
+              f"V={V}")
 
     print("ALL_HEAD_API_SHARDED_PASSED")
 """)
@@ -267,8 +290,9 @@ _SHARDED_SCRIPT = textwrap.dedent("""
 def test_sharded_kernel_head_subprocess():
     """make_head(spec, mesh) with impl='kernel': Pallas inside shard_map
     on 1- and 2-device meshes matches impl='sparton' and the unsharded
-    naive oracle (values + grads, incl. softcap), with the autotuner
-    keyed on the local vocab shard. Runs in a subprocess so the forced
+    naive oracle (values + grads, incl. softcap, and a vocab the mesh
+    does not divide), with the autotuner keyed on the local vocab
+    shard. Runs in a subprocess so the forced
     host-device count never leaks into the main pytest process."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)

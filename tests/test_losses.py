@@ -72,13 +72,11 @@ def test_gathered_infonce_single_device_axis_matches_local():
     exactly the local batch — loss must equal plain infonce."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
-
     ks = jax.random.split(jax.random.PRNGKey(3), 2)
     q = jax.random.normal(ks[0], (8, 16))
     d = jax.random.normal(ks[1], (8, 16))
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda a, b: gathered_infonce(a, b, axis_names=("data",)),
         mesh=mesh, in_specs=(P("data"), P("data")), out_specs=P(),
         check_vma=False)

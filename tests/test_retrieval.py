@@ -314,7 +314,10 @@ def test_make_config_encoder_emits_sparse_reps():
     mask = jnp.ones((2, 12), jnp.int32)
     rep = enc_sparse(toks, mask)
     assert isinstance(rep, SparseRep) and rep.width == 8
-    dense = np.asarray(enc_dense(toks, mask))
-    top8 = np.sort(np.argsort(dense, axis=1)[:, -8:], axis=1)
+    dense = np.asarray(enc_dense(toks, mask), np.float32)
+    # lax.top_k breaks ties toward the lower id, as a stable descending
+    # sort does (bf16 outputs tie often)
+    top8 = np.sort(np.argsort(-dense, axis=1, kind="stable")[:, :8],
+                   axis=1)
     got = np.sort(np.asarray(rep.indices), axis=1)
     np.testing.assert_array_equal(got, top8)

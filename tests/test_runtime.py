@@ -177,6 +177,47 @@ def test_step_exception_counts_as_failure(tmp_path):
     assert int(state["n"]) == 5
 
 
+def test_first_step_exception_is_raised(tmp_path):
+    """The first step compiles: its failure (a kernel that does not
+    compile) must stop the run, not be skipped like a lost device."""
+    clock = FakeClock()
+
+    def broken(state, batch):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    runner = FaultTolerantRunner(
+        broken, {"n": jnp.array(0)}, _batches(),
+        config=RunnerConfig(ckpt_dir=str(tmp_path), ckpt_every=0,
+                            max_steps=3),
+        clock=clock)
+    with pytest.raises(RuntimeError, match="compile"):
+        runner.run()
+    assert runner.skipped_steps == []
+
+
+def test_compile_cache_placed_from_outside(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; otherwise the
+    cache goes to one fixed directory inside the checkout."""
+    import os
+
+    import jax
+
+    from repro.launch import compile_cache as cc
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cc.CACHE_ENV, "/elsewhere/cache")
+        assert cc.setup_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(cc.CACHE_ENV)
+        path = cc.setup_compile_cache()
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_elastic_mesh_factorization():
     mgr = ElasticMeshManager(lambda shape: shape, model_axis=16)
     assert mgr.factorize(512) == (1, 32, 16)

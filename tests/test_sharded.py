@@ -21,8 +21,6 @@ _SCRIPT = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P, NamedSharding
 
     mesh = jax.make_mesh((2, 4), ("data", "model"))
-
-    from repro.compat import set_mesh
     from repro.core.lm_head import lm_head_sparton
     from repro.core.sharded import (sharded_sparton_head, sharded_infonce,
                                     sharded_flops_reg)
@@ -38,7 +36,7 @@ _SCRIPT = textwrap.dedent("""
 
     # ---- sharded sparton head == local head --------------------------
     head = sharded_sparton_head(mesh, batch_axes=("data",), vocab_tile=16)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_sharded = jax.jit(head)(H, E, b, mask)
     y_local = lm_head_sparton(H, E, b, mask, vocab_tile=16)
     np.testing.assert_allclose(np.asarray(y_sharded), np.asarray(y_local),
@@ -51,7 +49,7 @@ _SCRIPT = textwrap.dedent("""
     def loss_local(H, E, b):
         return jnp.sum(jnp.sin(lm_head_sparton(H, E, b, mask,
                                                vocab_tile=16)))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         gs = jax.jit(jax.grad(loss_sharded, (0, 1, 2)))(H, E, b)
     gl = jax.grad(loss_local, (0, 1, 2))(H, E, b)
     for a, c in zip(gs, gl):
@@ -63,7 +61,7 @@ _SCRIPT = textwrap.dedent("""
     yq = jax.random.normal(ks[4], (B, V))
     yd = jax.random.normal(jax.random.PRNGKey(9), (B, V))
     inf = sharded_infonce(mesh, batch_axes=("data",))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l_sharded = jax.jit(inf)(yq, yd)
     l_plain = infonce_loss(yq, yd)
     np.testing.assert_allclose(float(l_sharded), float(l_plain), atol=1e-5)
@@ -71,7 +69,7 @@ _SCRIPT = textwrap.dedent("""
 
     # ---- sharded flops reg == plain -----------------------------------
     fl = sharded_flops_reg(mesh, batch_axes=("data",))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         f_sharded = jax.jit(fl)(jnp.abs(yq))
     f_plain = flops_regularizer(jnp.abs(yq))
     np.testing.assert_allclose(float(f_sharded), float(f_plain),
@@ -82,7 +80,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.core.sharded import sharded_l1_reg, sharded_row_dots
     from repro.losses.contrastive import l1_regularizer, gathered_infonce
     l1 = sharded_l1_reg(mesh, batch_axes=("data",))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l1_sharded = jax.jit(l1)(jnp.abs(yq))
     np.testing.assert_allclose(float(l1_sharded),
                                float(l1_regularizer(jnp.abs(yq))),
@@ -91,7 +89,7 @@ _SCRIPT = textwrap.dedent("""
 
     # ---- sharded row dots == per-row einsum ---------------------------
     rd = sharded_row_dots(mesh, batch_axes=("data",))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         dots = jax.jit(rd)(yq, yd)
     np.testing.assert_allclose(np.asarray(dots),
                                np.asarray(jnp.einsum("bv,bv->b", yq, yd)),
@@ -99,12 +97,11 @@ _SCRIPT = textwrap.dedent("""
     print("OK sharded row dots")
 
     # ---- gathered infonce over the data axis == global infonce --------
-    from repro.compat import shard_map as _shard_map
-    gi = _shard_map(
+    gi = jax.shard_map(
         lambda a, c: gathered_infonce(a, c, axis_names=("data",)),
         mesh=mesh, in_specs=(P("data", None), P("data", None)),
         out_specs=P(), check_vma=False)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         l_gathered = jax.jit(gi)(yq, yd)
     np.testing.assert_allclose(float(l_gathered),
                                float(infonce_loss(yq, yd)), atol=1e-5)
@@ -112,7 +109,6 @@ _SCRIPT = textwrap.dedent("""
 
     # ---- expert-parallel MoE == local MoE -----------------------------
     from repro.models.moe import moe_ffn, moe_ffn_local_experts
-    from repro.compat import shard_map
     T, Dm, F, Eexp = 16, 8, 12, 4
     x = jax.random.normal(jax.random.PRNGKey(11), (T, Dm))
     router = jax.random.normal(jax.random.PRNGKey(12), (Dm, Eexp))
@@ -125,12 +121,12 @@ _SCRIPT = textwrap.dedent("""
     body = functools.partial(moe_ffn_local_experts, top_k=2,
                              capacity_factor=8.0, expert_axis="model",
                              token_axes=("data",))
-    fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                    in_specs=(P("data", None), P(None, None),
                              P("model", None, None), P("model", None, None),
                              P("model", None, None)),
                    out_specs=(P("data", None), P()))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out_ep, aux_ep = jax.jit(fn)(x, router, wg, wu, wd)
     # high capacity => no drops on either path => identical outputs
     np.testing.assert_allclose(np.asarray(out_ep), np.asarray(out_local),
@@ -146,11 +142,11 @@ _SCRIPT = textwrap.dedent("""
         mean, resid = compressed_allreduce({"w": gw, "b": gb}, None,
                                            "data")
         return mean["w"], mean["b"]
-    fn2 = shard_map(car, mesh=mesh,
+    fn2 = jax.shard_map(car, mesh=mesh,
                     in_specs=(P("data", None), P("data", None)),
                     out_specs=(P(None, None), P(None, None)),
                     check_vma=False)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         mw, mb = jax.jit(fn2)(g_tree["w"], g_tree["b"])
     # each data shard holds 4 rows; mean over the 2 shards
     ref_w = (np.asarray(g_tree["w"][:4]) + np.asarray(g_tree["w"][4:])) / 2
@@ -165,11 +161,11 @@ _SCRIPT = textwrap.dedent("""
     rows, dd, R = 64, 16, 4096
     src2 = jax.random.normal(jax.random.PRNGKey(30), (rows, dd))
     idx2 = jax.random.randint(jax.random.PRNGKey(31), (R,), 0, rows)
-    take2 = shard_map(
+    take2 = jax.shard_map(
         lambda s, i: distributed_take_local(s, i, axis_names=axes2),
         mesh=mesh, in_specs=(P(axes2, None), P(axes2)),
         out_specs=(P(axes2, None), P()), check_vma=False)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got, ndrop = jax.jit(take2)(src2, idx2)
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(jnp.take(src2, idx2, axis=0)),
@@ -179,12 +175,12 @@ _SCRIPT = textwrap.dedent("""
 
     vals2 = jax.random.normal(jax.random.PRNGKey(32), (R, dd))
     dst2 = jax.random.randint(jax.random.PRNGKey(33), (R,), 0, rows)
-    scat2 = shard_map(
+    scat2 = jax.shard_map(
         lambda v, i: distributed_segment_sum_local(
             v, i, rows // 8, axis_names=axes2),
         mesh=mesh, in_specs=(P(axes2, None), P(axes2)),
         out_specs=(P(axes2, None), P()), check_vma=False)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out3, ndrop3 = jax.jit(scat2)(vals2, dst2)
     np.testing.assert_allclose(
         np.asarray(out3),
@@ -198,7 +194,7 @@ _SCRIPT = textwrap.dedent("""
     table = jax.random.normal(jax.random.PRNGKey(22), (32, 8))
     idx = jnp.array([0, 5, 17, 31, 8])
     lookup = make_sharded_lookup(mesh, axis_name="model")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lookup)(table, idx)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(jnp.take(table, idx, axis=0)),
