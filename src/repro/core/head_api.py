@@ -330,6 +330,8 @@ def make_encoder(
         return head
 
     def encode(H, E, b=None, mask=None):
-        return sparsify(head(H, E, b, mask))
+        y = head(H, E, b, mask)
+        with jax.named_scope("sparsify"):
+            return sparsify(y)
 
     return encode

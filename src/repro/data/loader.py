@@ -14,6 +14,7 @@ import queue
 import threading
 from typing import Callable, Dict, Iterator, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 
@@ -53,7 +54,9 @@ class HostShardedLoader:
         return self
 
     def __next__(self) -> Dict[str, np.ndarray]:
-        item = self._q.get()
+        # the wait for input, on the profiler's clock when one records
+        with jax.profiler.TraceAnnotation("loader.next"):
+            item = self._q.get()
         if item is None:
             raise StopIteration
         return item

@@ -164,14 +164,16 @@ def build_lsr_train_step(
         loss, grads = microbatch_grads(
             grad_fn, state["params"], batch, n_micro=n_micro,
             unroll=micro_unroll, grad_specs=zero_specs)
-        updates, opt_state = opt.update(
-            grads, state["opt"], state["params"], state["step"])
-        # cast at the ZeRO sharding, THEN all-gather in param dtype
-        updates = jax.tree.map(lambda u, p: u.astype(p.dtype),
-                               updates, state["params"])
-        if param_specs is not None:
-            updates = jax.lax.with_sharding_constraint(updates, param_specs)
-        params = apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = opt.update(
+                grads, state["opt"], state["params"], state["step"])
+            # cast at the ZeRO sharding, THEN all-gather in param dtype
+            updates = jax.tree.map(lambda u, p: u.astype(p.dtype),
+                                   updates, state["params"])
+            if param_specs is not None:
+                updates = jax.lax.with_sharding_constraint(updates,
+                                                           param_specs)
+            params = apply_updates(state["params"], updates)
         new_state = {"params": params, "opt": opt_state,
                      "step": state["step"] + 1}
         return new_state, {"loss": loss}

@@ -173,6 +173,9 @@ def _layer(
 # trunk forward (scan over stacked layers)
 # ---------------------------------------------------------------------------
 
+# every op of the encoder body, forward and backward, carries "backbone"
+# in its op_name, from the embedding gather to the final norm
+@jax.named_scope("backbone")
 def forward_hidden(
     params: Params,
     cfg: TransformerConfig,

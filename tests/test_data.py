@@ -1,6 +1,10 @@
 """Data pipeline: determinism, shard disjointness, shapes, loader."""
 
+import glob
+
+import jax
 import numpy as np
+import pytest
 
 from repro.data.loader import HostShardedLoader, length_bucket
 from repro.data.synthetic import (lm_token_batches, lsr_pair_batches,
@@ -70,6 +74,35 @@ def test_host_sharded_loader_prefetch():
     loader = HostShardedLoader(make_iter, prefetch=2)
     got = [b["x"][0] for b in loader]
     assert got == [0, 1, 2, 3, 4]
+
+
+@pytest.mark.parametrize("profiled", [False, True])
+def test_host_sharded_loader_yields_the_source_batches(profiled, tmp_path):
+    def make_iter(shard, n_shards):
+        rng = np.random.default_rng(shard)
+        for _ in range(4):
+            yield {"x": rng.standard_normal(3)}
+
+    want = list(make_iter(0, 1))
+    if profiled:
+        jax.profiler.start_trace(str(tmp_path))
+    try:
+        got = list(HostShardedLoader(make_iter))
+    finally:
+        if profiled:
+            jax.profiler.stop_trace()
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g["x"], w["x"])
+    if profiled:
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        pd = jax.profiler.ProfileData.from_file(path)
+        waits = [ev for plane in pd.planes if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events
+                 if ev.name == "loader.next"]
+        # one span a call: four batches and the call that ends the loop
+        assert len(waits) == 5
 
 
 def test_length_bucket():
