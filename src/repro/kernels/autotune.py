@@ -13,11 +13,17 @@ traffic and VMEM residency, and the best point moves with the shape:
   backward, the ``(block_b, block_s, D)`` / ``(block_v, D)`` backward
   scratch accumulators too.
 
-This module enumerates candidates under a VMEM budget, scores them
-analytically (``heuristic_blocks``), optionally *times* them
-(``autotune_blocks`` — on a TPU the real kernel, elsewhere the Pallas
-interpreter on a capped proxy shape), and persists measured winners in
-a JSON cache keyed by ``(B, S, D, V, dtype, backend)``.
+The kernels take rows in length order and skip the sequence tiles past
+each row block's extent (``kernels/sparton.live_tiles``), which changes
+the best point again: short sequence tiles let the skip bite, at the
+cost of more grid steps. This module enumerates candidates under a VMEM
+budget, ranks them by their distance from a rule timed on the chip
+(``_preferred_blocks``; the analytic traffic model breaks ties), takes
+the first (``heuristic_blocks``) or *times* the first few on the
+traffic's own mask (``autotune_blocks`` — on a TPU the real kernel,
+elsewhere the Pallas interpreter on a capped proxy shape), and persists
+measured winners in a JSON cache keyed by ``(B, S, D, V, dtype,
+backend)``.
 
 ``get_blocks`` is the cheap entry point used by the kernel wrappers
 when no explicit blocks are passed: cache hit, else heuristic — never
@@ -27,6 +33,7 @@ a measurement (safe to call under ``jax.jit`` tracing).
 from __future__ import annotations
 
 import json
+import math
 import os
 import time
 import warnings
@@ -65,11 +72,23 @@ VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES
 
 # Mosaic tiling: a block's last two dims are multiples of (8, 128) or
 # the whole array dim. block_b sits on the sublanes of the (B, V)
-# tiles, block_s on the lanes of the (B, S) mask, block_v on the lanes
-# of the (B, V) tiles.
-_BB_CHOICES = (8, 16, 32)
-_BS_CHOICES = (128, 256, 512)
+# tiles, block_s on the sublanes of the H and dH tiles (16 holds a
+# packed bf16 tile), block_v on the lanes of the (B, V) tiles.
+_BB_CHOICES = (8, 16, 32, 64)
+_BS_CHOICES = (16, 32, 64, 128, 256, 512)
 _BV_CHOICES = (128, 256, 512, 1024, 2048)
+
+# The kernels take rows in length order and skip the sequence tiles
+# past each row block's extent (kernels/sparton.live_tiles), so short
+# sequence tiles pay for their extra grid steps. Timed on a TPU v5e at
+# the benchmark cells' shapes with their length-ordered masks (PERF.md
+# section 6): 32-position tiles (16 where the sequence is at most 32
+# long) with these rows (block_b * block_s) and vocab tiles a step ran
+# each kernel within 6% of the fastest block tried, in 0.42-0.50 of the
+# time of the dense blocks chosen before at 256 positions, 0.67-0.83 at
+# 32.
+_SKIP_ROWS = {"fwd": 1024, "dh": 1024, "de": 512}
+_SKIP_BV = {"fwd": 2048, "dh": 512, "de": 2048}
 # block_s may instead be the whole sequence, padded to the bf16
 # sublane tile, so short sequences need not pad to 128.
 _S_ALIGN = 16
@@ -247,13 +266,15 @@ def candidate_blocks(
     pinned: Optional[Pinned] = None,
     kernel: Optional[str] = None,
 ) -> List[Blocks]:
-    """All (block_b, block_s, block_v) under the VMEM budget, best first.
+    """All (block_b, block_s, block_v) under the VMEM budget, least
+    traffic first.
 
     Candidates keep Mosaic's tiling rules (block_b a multiple of 8;
-    block_s a multiple of 128 or the whole padded sequence; block_v a
-    multiple of 128) and skip blocks grossly larger than the padded
-    problem. Sorted by the analytic HBM-traffic model, least traffic
-    first. ``pinned``
+    block_s a multiple of 16, the packed bf16 sublane tile, or the whole
+    padded sequence; block_v a multiple of 128) and skip blocks grossly
+    larger than the padded problem. Sorted by the analytic HBM-traffic
+    model of a full mask, least traffic first (``ranked_blocks`` puts
+    them in the order the block choice uses). ``pinned``
     components (from a config) are honored exactly — only the free
     components are enumerated, and the VMEM budget is checked on the
     *combined* triple. ``kernel`` scopes both the VMEM residency and
@@ -284,21 +305,51 @@ def candidate_blocks(
     return out
 
 
+def _preferred_blocks(S: int, kernel: Optional[str] = None) -> Blocks:
+    """The measured rule on shapes: ``block_s`` 32, or 16 where ``S`` is
+    at most 32, and the kernel's rows and vocab tile a step (the joint
+    triple takes the forward's)."""
+    bs = 16 if S <= 32 else 32
+    kn = kernel or "fwd"
+    return (_SKIP_ROWS[kn] // bs, bs, _SKIP_BV[kn])
+
+
+def ranked_blocks(B: int, S: int, D: int, V: int,
+                  *, dtype=jnp.float32,
+                  vmem_budget: int = VMEM_BUDGET_BYTES,
+                  pinned: Optional[Pinned] = None,
+                  kernel: Optional[str] = None) -> List[Blocks]:
+    """``candidate_blocks`` nearest ``_preferred_blocks`` first: its
+    ``block_s`` first, then its rows a step, then its vocab tile, the
+    traffic model breaking ties (it picks among blocks the shape or the
+    VMEM budget leave when the preferred ones do not fit). The one
+    order both ``heuristic_blocks`` and the timed tuners take."""
+    cands = candidate_blocks(B, S, D, V, dtype=dtype,
+                             vmem_budget=vmem_budget, pinned=pinned,
+                             kernel=kernel)
+    pb, ps, pv = _preferred_blocks(S, kernel)
+    # sorted is stable: equals keep their traffic order
+    return sorted(cands, key=lambda c: (
+        c[1] != ps,
+        abs(math.log2(c[0] * c[1] / (pb * ps))),
+        abs(math.log2(c[2] / pv))))
+
+
 def heuristic_blocks(B: int, S: int, D: int, V: int,
                      *, dtype=jnp.float32,
                      vmem_budget: int = VMEM_BUDGET_BYTES,
                      pinned: Optional[Pinned] = None,
                      kernel: Optional[str] = None) -> Blocks:
-    """Best candidate by the analytic model — no measurement.
+    """The first of ``ranked_blocks`` — no measurement.
 
     With pins, the free components shrink as needed to keep the
     combined triple under the budget; if no free choice fits (the pins
     alone overflow), the smallest free components are used so the
     overflow is at least minimal, not amplified.
     """
-    cands = candidate_blocks(B, S, D, V, dtype=dtype,
-                             vmem_budget=vmem_budget, pinned=pinned,
-                             kernel=kernel)
+    cands = ranked_blocks(B, S, D, V, dtype=dtype,
+                          vmem_budget=vmem_budget, pinned=pinned,
+                          kernel=kernel)
     if cands:
         return cands[0]
     if pinned and any(p is not None for p in pinned):
@@ -347,6 +398,22 @@ def _measure_shape(B: int, S: int, V: int,
     return min(B, 8), min(S, 256), min(V, 2048)
 
 
+def _tuning_mask(mask: Optional[jax.Array], B: int, S: int, mb: int,
+                 ms: int) -> jax.Array:
+    """The mask the tuners time: the traffic's own ``(B, S)`` mask (a
+    full one where none is given) cut to the measured shape, its rows in
+    length order as ``kernels/ops.py`` takes them."""
+    from repro.kernels.sparton import row_extents
+
+    if mask is None:
+        return jnp.ones((mb, ms), jnp.int32)
+    if tuple(mask.shape) != (B, S):
+        raise ValueError(f"mask shape {tuple(mask.shape)} is not the "
+                         f"tuned shape {(B, S)}")
+    mask = jnp.asarray(mask, jnp.int32)[:mb, :ms]
+    return mask[jnp.argsort(-row_extents(mask), stable=True)]
+
+
 def _time_ms(fn, *args, warmup: int = 1, iters: int = 3) -> float:
     for _ in range(warmup):
         jax.block_until_ready(fn(*args))
@@ -369,13 +436,17 @@ def autotune_blocks(
     include_backward: bool = True,
     path: Optional[str] = None,
     vmem_budget: int = VMEM_BUDGET_BYTES,
+    mask: Optional[jax.Array] = None,
 ) -> Blocks:
     """Time block candidates for the shape, persist and return the winner.
 
     On a TPU the real Mosaic kernels are timed at the real shape; on
     CPU/GPU hosts (``interpret`` defaults to True there) the Pallas
     interpreter is timed on a capped proxy shape — a rough but
-    deterministic ordering that keeps CI and laptops tune-able.
+    deterministic ordering that keeps CI and laptops tune-able. The
+    first ``max_candidates`` of ``ranked_blocks`` are timed on ``mask``,
+    a ``(B, S)`` keep mask of the traffic to tune for (None: a full
+    mask), so the skipped tiles count as they will in the run.
     """
     from repro.kernels.ops import sparton_head
     from repro.kernels.sparton import sparton_forward
@@ -390,8 +461,8 @@ def autotune_blocks(
     if hit is not None and hit.get("source") == "measured":
         return (hit["block_b"], hit["block_s"], hit["block_v"])
 
-    cands = candidate_blocks(B, S, D, V, dtype=dtype,
-                             vmem_budget=vmem_budget)[:max_candidates]
+    cands = ranked_blocks(B, S, D, V, dtype=dtype,
+                          vmem_budget=vmem_budget)[:max_candidates]
     if not cands:
         cands = [MIN_BLOCKS]
 
@@ -400,7 +471,7 @@ def autotune_blocks(
     H = jax.random.normal(ks[0], (mb, ms, D), dtype)
     E = jax.random.normal(ks[1], (mv, D), dtype) * 0.2
     bias = jax.random.normal(ks[2], (mv,), jnp.float32) * 0.2
-    mask = jnp.ones((mb, ms), jnp.int32)
+    mask = _tuning_mask(mask, B, S, mb, ms)
 
     best: Tuple[float, Blocks] = (float("inf"), cands[0])
     last_error: Optional[Exception] = None
@@ -467,6 +538,7 @@ def autotune_kernel_blocks(
     max_candidates: int = 8,
     path: Optional[str] = None,
     vmem_budget: int = VMEM_BUDGET_BYTES,
+    mask: Optional[jax.Array] = None,
 ) -> Dict[str, Blocks]:
     """Time block candidates **per kernel** (fwd, dH, dE), persist and
     return ``{kernel: winner}``.
@@ -477,9 +549,10 @@ def autotune_kernel_blocks(
     tuner times each kernel in isolation on its own candidate set and
     writes one cache entry per kernel (``<shape>_fwd`` etc.); the
     wrappers' per-kernel lookups pick them up, and old joint entries
-    remain readable as the fallback.
+    remain readable as the fallback. Candidates and ``mask`` as in
+    ``autotune_blocks``.
     """
-    from repro.kernels.sparton import sparton_forward
+    from repro.kernels.sparton import row_extents, sparton_forward
     from repro.kernels.sparton_bwd import (sparton_backward_de,
                                            sparton_backward_dh)
 
@@ -501,7 +574,8 @@ def autotune_kernel_blocks(
     H = jax.random.normal(ks[0], (mb, ms, D), dtype)
     E = jax.random.normal(ks[1], (mv, D), dtype) * 0.2
     bias = jax.random.normal(ks[2], (mv,), jnp.float32) * 0.2
-    mask = jnp.ones((mb, ms), jnp.int32)
+    mask = _tuning_mask(mask, B, S, mb, ms)
+    extents = row_extents(mask)
     # one forward at heuristic blocks supplies the backward operands
     fwd_heur = heuristic_blocks(mb, ms, D, mv, dtype=dtype,
                                 vmem_budget=vmem_budget, kernel="fwd")
@@ -519,13 +593,13 @@ def autotune_kernel_blocks(
     def dh_fn(blocks):
         bb, bs, bv = blocks
         return lambda: sparton_backward_dh(
-            dy, y, i_max, E, ms, block_b=bb, block_s=bs, block_v=bv,
-            softcap=softcap, interpret=interpret)
+            dy, y, i_max, E, ms, extents, block_b=bb, block_s=bs,
+            block_v=bv, softcap=softcap, interpret=interpret)
 
     def de_fn(blocks):
         bb, bs, bv = blocks
         return lambda: sparton_backward_de(
-            dy, y, i_max, H, block_b=bb, block_s=bs, block_v=bv,
+            dy, y, i_max, H, extents, block_b=bb, block_s=bs, block_v=bv,
             softcap=softcap, interpret=interpret)
 
     builders = {"fwd": fwd_fn, "dh": dh_fn, "de": de_fn}
@@ -537,9 +611,9 @@ def autotune_kernel_blocks(
             winners[kn] = (hit["block_b"], hit["block_s"],
                            hit["block_v"])
             continue
-        cands = candidate_blocks(B, S, D, V, dtype=dtype,
-                                 vmem_budget=vmem_budget,
-                                 kernel=kn)[:max_candidates]
+        cands = ranked_blocks(B, S, D, V, dtype=dtype,
+                              vmem_budget=vmem_budget,
+                              kernel=kn)[:max_candidates]
         if not cands:
             cands = [MIN_BLOCKS]
         best: Tuple[float, Blocks] = (float("inf"), cands[0])

@@ -2,8 +2,12 @@
 
 ``sparton_lm_head_kernel`` is the drop-in kernel-backed equivalent of
 ``repro.core.lm_head.lm_head_sparton``: a ``jax.custom_vjp`` whose
-forward runs the fused Pallas forward (saving only ``(y, i_max)``) and
-whose backward runs the two fused Pallas accumulation kernels. The v2
+forward runs the fused Pallas forward (saving only ``(y, i_max)``,
+beside the ``H`` the backward needs) and whose backward runs the two
+fused Pallas accumulation kernels. Inside it the rows are taken in
+length order, so that the kernels can skip the sequence tiles past
+each row block's last real position; the caller sees its own order
+(DESIGN.md §5, "Length-ordered rows and the extent table"). The v2
 backward consumes the raw cotangent directly — the activation-
 derivative factor ``g = dy * f'(y)`` and the bias gradient
 ``db = sum_b g`` are computed inside the kernels, so no standalone
@@ -30,10 +34,18 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.sparton import sparton_forward
+from repro.kernels.sparton import row_extents, sparton_forward
 from repro.kernels.sparton_bwd import sparton_backward
 
 Blocks = Tuple[int, int, int]
+
+
+def _unorder(x_o, perm, dtype):
+    """Rows back in the caller's order, cast in the same pass."""
+    with jax.named_scope("head_order"):
+        inv = jnp.zeros_like(perm).at[perm].set(
+            jnp.arange(perm.shape[0], dtype=perm.dtype))
+        return jnp.take(x_o, inv, axis=0, mode="clip").astype(dtype)
 
 
 @functools.partial(jax.custom_vjp,
@@ -52,38 +64,48 @@ def sparton_lm_head_kernel(
     dh_blocks: Optional[Blocks] = None,
     de_blocks: Optional[Blocks] = None,
 ) -> jax.Array:
-    y, _ = sparton_forward(
-        H, E, b, mask,
-        block_b=block_b, block_s=block_s, block_v=block_v,
-        softcap=softcap, interpret=interpret,
-    )
-    return y.astype(out_dtype or H.dtype)
+    y, _ = _fwd(H, E, b, mask, block_b, block_s, block_v, softcap,
+                interpret, out_dtype, dh_blocks, de_blocks)
+    return y
 
 
 def _fwd(H, E, b, mask, block_b, block_s, block_v, softcap, interpret,
          out_dtype, dh_blocks, de_blocks):
-    y, i_max = sparton_forward(
-        H, E, b, mask,
+    # Rows in length order, longest first: rows of like extent share a
+    # row block, so the kernels skip the sequence tiles past each
+    # block's extent. i_max holds sequence positions, which the order
+    # leaves as they are.
+    with jax.named_scope("head_order"):
+        extents = row_extents(mask)
+        perm = jnp.argsort(-extents, stable=True)
+        H_o, mask_o, ext_o = (jnp.take(x, perm, axis=0, mode="clip")
+                              for x in (H, mask, extents))
+    y_o, i_o = sparton_forward(
+        H_o, E, b, mask_o,
         block_b=block_b, block_s=block_s, block_v=block_v,
         softcap=softcap, interpret=interpret,
     )
-    return y.astype(out_dtype or H.dtype), (H, E, y, i_max)
+    # the ordered H stands in for H: no residual holds both
+    return (_unorder(y_o, perm, out_dtype or H.dtype),
+            (H_o, E, y_o, i_o, ext_o, perm))
 
 
 def _bwd(block_b, block_s, block_v, softcap, interpret, out_dtype,
          dh_blocks, de_blocks, res, dy):
-    H, E, y, i_max = res
+    H_o, E, y_o, i_o, ext_o, perm = res
+    with jax.named_scope("head_order"):
+        dy_o = jnp.take(dy, perm, axis=0, mode="clip")
     # v2: dy and y go straight into the kernels; g and db are computed
     # tile-wise in their epilogues. Each backward contraction runs with
     # its own blocks (explicit triples win; else block_* pins apply
     # jointly; else per-kernel autotune cache).
-    dH, dE, db = sparton_backward(
-        dy, y, i_max, H, E,
+    dH_o, dE, db = sparton_backward(
+        dy_o, y_o, i_o, H_o, E, ext_o,
         block_b=block_b, block_s=block_s, block_v=block_v,
         dh_blocks=dh_blocks, de_blocks=de_blocks,
         softcap=softcap, interpret=interpret,
     )
-    return dH.astype(H.dtype), dE.astype(E.dtype), db, None
+    return _unorder(dH_o, perm, H_o.dtype), dE.astype(E.dtype), db, None
 
 
 sparton_lm_head_kernel.defvjp(_fwd, _bwd)

@@ -53,9 +53,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels._common import (bwd_factor, compiler_params,
                                    onehot_weights, pad_to)
+from repro.kernels.sparton import last_live_tile, live_tiles
 
 
 def _dh_kernel(
+    live_ref,  # (B/bb,) i32 SMEM — live sequence tiles per row block
     dy_ref,    # (bb, bv) f32 — raw upstream cotangent
     y_ref,     # (bb, bv) f32 — stored post-activation
     i_ref,     # (bb, bv) i32 — argmax sequence index
@@ -67,24 +69,28 @@ def _dh_kernel(
     block_s: int,
     softcap: Optional[float],
 ):
+    i = pl.program_id(0)
+    k = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    bb, bs, d = dh_ref.shape
-    k = pl.program_id(1)
-
-    g = bwd_factor(y_ref[...], dy_ref[...], softcap)     # fused epilogue
-    local_i = i_ref[...] - k * block_s          # (bb, bv); in-range => hit
-    w = onehot_weights(g, local_i, bs)          # (bb, bs, bv)
-    # dH[b, s, :] += sum_v w[b, s, v] * E[v, :]  — one MXU contraction.
-    contrib = jax.lax.dot_general(
-        w.reshape(bb * bs, -1), e_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    ).reshape(bb, bs, d)
-    acc_ref[...] += contrib
+    # No arg-max points past the row block's extent, so a tile there
+    # runs no vocab step and is written as the zeros of _init.
+    @pl.when(k < live_ref[i])
+    def _accumulate():
+        bb, bs, d = dh_ref.shape
+        g = bwd_factor(y_ref[...], dy_ref[...], softcap)  # fused epilogue
+        local_i = i_ref[...] - k * block_s      # (bb, bv); in-range => hit
+        w = onehot_weights(g, local_i, bs)      # (bb, bs, bv)
+        # dH[b, s, :] += sum_v w[b, s, v] * E[v, :]  — one MXU contraction.
+        contrib = jax.lax.dot_general(
+            w.reshape(bb * bs, -1), e_ref[...].astype(jnp.float32),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        ).reshape(bb, bs, d)
+        acc_ref[...] += contrib
 
     @pl.when(j == n_v_blocks - 1)
     def _finalize():
@@ -92,6 +98,7 @@ def _dh_kernel(
 
 
 def _de_kernel(
+    live_ref,  # (B/bb,) i32 SMEM — live sequence tiles per row block
     dy_ref,    # (bb, bv) f32
     y_ref,     # (bb, bv) f32
     i_ref,     # (bb, bv) i32
@@ -114,17 +121,21 @@ def _de_kernel(
         de_acc[...] = jnp.zeros(de_acc.shape, jnp.float32)
         db_acc[...] = jnp.zeros(db_acc.shape, jnp.float32)
 
-    bb, bs, _ = h_ref.shape
-
     g = bwd_factor(y_ref[...], dy_ref[...], softcap)     # fused epilogue
-    local_i = i_ref[...] - k * block_s
-    w = onehot_weights(g, local_i, bs).reshape(bb * bs, -1)
-    # dE[v, :] += sum_{b,s} w[bs, v] * H[bs, :]
-    contrib = jax.lax.dot_general(
-        w, h_ref[...].reshape(bb * bs, -1).astype(jnp.float32),
-        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )
-    de_acc[...] += contrib
+
+    # A tile past the row block's extent holds no arg-max: it adds
+    # nothing to dE.
+    @pl.when(k < live_ref[i])
+    def _accumulate():
+        bb, bs, _ = h_ref.shape
+        local_i = i_ref[...] - k * block_s
+        w = onehot_weights(g, local_i, bs).reshape(bb * bs, -1)
+        # dE[v, :] += sum_{b,s} w[bs, v] * H[bs, :]
+        contrib = jax.lax.dot_general(
+            w, h_ref[...].reshape(bb * bs, -1).astype(jnp.float32),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        de_acc[...] += contrib
 
     # db[v] = sum_b g[b, v] — independent of s, so add once per b block.
     @pl.when(k == 0)
@@ -142,7 +153,8 @@ def _de_kernel(
 # (v), so the best blocks differ (the autotuner times them apart —
 # ROADMAP per-kernel item). Padding invariant shared by both: padded
 # rows/cols must not route anywhere real — y == 0 there, so bwd_factor
-# yields g == 0 and any index is safe.
+# yields g == 0 and any index is safe. ``live`` is each kernel's own
+# extent table (``sparton.live_tiles`` at its blocks).
 
 @functools.partial(
     jax.jit,
@@ -150,7 +162,7 @@ def _de_kernel(
                      "softcap", "interpret"),
 )
 def _dh_call(
-    dy, y, i_max, E, *, seq_len, block_b, block_s, block_v, softcap,
+    dy, y, i_max, E, live, *, seq_len, block_b, block_s, block_v, softcap,
     interpret
 ):
     B, V = dy.shape
@@ -166,27 +178,37 @@ def _dh_call(
     Sp = -(-seq_len // block_s) * block_s
     nb, ns, nv = Bp // block_b, Sp // block_s, Vp // block_v
 
-    bv_spec = pl.BlockSpec((block_b, block_v), lambda i, k, j: (i, j))
+    def vocab_block(i, k, j, lv):
+        # a skipped tile keeps the last vocab block: no DMA
+        return jnp.where(k < lv[i], j, nv - 1)
+
+    bv_spec = pl.BlockSpec((block_b, block_v),
+                           lambda i, k, j, lv: (i, vocab_block(i, k, j, lv)))
     dH = pl.pallas_call(
         functools.partial(_dh_kernel, n_v_blocks=nv, block_s=block_s,
                           softcap=softcap),
-        grid=(nb, ns, nv),
-        in_specs=[
-            bv_spec,
-            bv_spec,
-            bv_spec,
-            pl.BlockSpec((block_v, D), lambda i, k, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (block_b, block_s, D), lambda i, k, j: (i, k, 0)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nb, ns, nv),
+            in_specs=[
+                bv_spec,
+                bv_spec,
+                bv_spec,
+                pl.BlockSpec((block_v, D),
+                             lambda i, k, j, lv: (vocab_block(i, k, j, lv),
+                                                  0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (block_b, block_s, D), lambda i, k, j, lv: (i, k, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((block_b, block_s, D), jnp.float32),
+            ],
         ),
         out_shape=jax.ShapeDtypeStruct((Bp, Sp, D), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((block_b, block_s, D), jnp.float32),
-        ],
         compiler_params=compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(dyp, yp, ip, Ep)
+    )(live, dyp, yp, ip, Ep)
     return dH[:B, :seq_len]
 
 
@@ -196,7 +218,7 @@ def _dh_call(
                      "interpret"),
 )
 def _de_call(
-    dy, y, i_max, H, *, block_b, block_s, block_v, softcap, interpret
+    dy, y, i_max, H, live, *, block_b, block_s, block_v, softcap, interpret
 ):
     B, V = dy.shape
     S, D = H.shape[1], H.shape[2]
@@ -210,35 +232,40 @@ def _de_call(
     Vp = dyp.shape[1]
     nb, ns, nv = Bp // block_b, Sp // block_s, Vp // block_v
 
-    vb_spec = pl.BlockSpec((block_b, block_v), lambda j, i, k: (i, j))
+    vb_spec = pl.BlockSpec((block_b, block_v), lambda j, i, k, lv: (i, j))
     dE, db = pl.pallas_call(
         functools.partial(
             _de_kernel, n_b_blocks=nb, n_s_blocks=ns, block_s=block_s,
             softcap=softcap,
         ),
-        grid=(nv, nb, ns),
-        in_specs=[
-            vb_spec,
-            vb_spec,
-            vb_spec,
-            pl.BlockSpec((block_b, block_s, D), lambda j, i, k: (i, k, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_v, D), lambda j, i, k: (j, 0)),
-            pl.BlockSpec((1, block_v), lambda j, i, k: (0, j)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nv, nb, ns),
+            in_specs=[
+                vb_spec,
+                vb_spec,
+                vb_spec,
+                pl.BlockSpec((block_b, block_s, D),
+                             lambda j, i, k, lv:
+                             (i, last_live_tile(lv, i, k), 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((block_v, D), lambda j, i, k, lv: (j, 0)),
+                pl.BlockSpec((1, block_v), lambda j, i, k, lv: (0, j)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_v, D), jnp.float32),
+                pltpu.VMEM((1, block_v), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((Vp, D), jnp.float32),
             jax.ShapeDtypeStruct((1, Vp), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_v, D), jnp.float32),
-            pltpu.VMEM((1, block_v), jnp.float32),
-        ],
         compiler_params=compiler_params("parallel", "arbitrary",
                                         "arbitrary"),
         interpret=interpret,
-    )(dyp, yp, ip, Hp)
+    )(live, dyp, yp, ip, Hp)
     return dE[:V], db[0, :V]
 
 
@@ -266,6 +293,7 @@ def sparton_backward_dh(
     i_max: jax.Array,   # (B, V) i32
     E: jax.Array,       # (V, D) f32 or bf16
     seq_len: int,
+    extents: jax.Array,  # (B,) i32 — each row's last real position + 1
     *,
     block_b: Optional[int] = None,
     block_s: Optional[int] = None,
@@ -277,9 +305,9 @@ def sparton_backward_dh(
     B, V = dy.shape
     blocks = _resolve((B, seq_len, E.shape[1]), V, E.dtype, "dh",
                       block_b, block_s, block_v)
-    return _dh_call(dy, y, i_max, E, seq_len=seq_len, block_b=blocks[0],
-                    block_s=blocks[1], block_v=blocks[2],
-                    softcap=softcap, interpret=interpret)
+    return _dh_call(dy, y, i_max, E, live_tiles(extents, *blocks[:2]),
+                    seq_len=seq_len, block_b=blocks[0], block_s=blocks[1],
+                    block_v=blocks[2], softcap=softcap, interpret=interpret)
 
 
 def sparton_backward_de(
@@ -287,6 +315,7 @@ def sparton_backward_de(
     y: jax.Array,       # (B, V) f32
     i_max: jax.Array,   # (B, V) i32
     H: jax.Array,       # (B, S, D) f32 or bf16
+    extents: jax.Array,  # (B,) i32 — each row's last real position + 1
     *,
     block_b: Optional[int] = None,
     block_s: Optional[int] = None,
@@ -297,8 +326,8 @@ def sparton_backward_de(
     """The dE (+ fused db) contraction alone — the autotuner's unit."""
     blocks = _resolve(H.shape, dy.shape[1], H.dtype, "de",
                       block_b, block_s, block_v)
-    return _de_call(dy, y, i_max, H, block_b=blocks[0],
-                    block_s=blocks[1], block_v=blocks[2],
+    return _de_call(dy, y, i_max, H, live_tiles(extents, *blocks[:2]),
+                    block_b=blocks[0], block_s=blocks[1], block_v=blocks[2],
                     softcap=softcap, interpret=interpret)
 
 
@@ -308,6 +337,7 @@ def sparton_backward(
     i_max: jax.Array,   # (B, V) i32
     H: jax.Array,       # (B, S, D) f32 or bf16
     E: jax.Array,       # (V, D) f32 or bf16
+    extents: jax.Array,  # (B,) i32 — each row's last real position + 1
     *,
     block_b: Optional[int] = None,
     block_s: Optional[int] = None,
@@ -327,7 +357,12 @@ def sparton_backward(
     contractions (the legacy joint behavior); unset components come
     from the autotuner's per-kernel cache ("dh" / "de" entries, falling
     back to a legacy joint entry when only that exists).
+
+    ``extents`` (``sparton.row_extents`` of the mask) lets both kernels
+    skip the sequence tiles past their row block's extent; any extent at
+    or past a row's last real position gives the same result.
     """
+    S = H.shape[1]
     V = E.shape[0]
     if dh_blocks is None:
         dh_blocks = _resolve(H.shape, V, E.dtype, "dh",
@@ -335,11 +370,12 @@ def sparton_backward(
     if de_blocks is None:
         de_blocks = _resolve(H.shape, V, H.dtype, "de",
                              block_b, block_s, block_v)
-    dH = _dh_call(dy, y, i_max, E, seq_len=H.shape[1],
-                  block_b=dh_blocks[0], block_s=dh_blocks[1],
+    dH = _dh_call(dy, y, i_max, E, live_tiles(extents, *dh_blocks[:2]),
+                  seq_len=S, block_b=dh_blocks[0], block_s=dh_blocks[1],
                   block_v=dh_blocks[2], softcap=softcap,
                   interpret=interpret)
-    dE, db = _de_call(dy, y, i_max, H, block_b=de_blocks[0],
-                      block_s=de_blocks[1], block_v=de_blocks[2],
-                      softcap=softcap, interpret=interpret)
+    dE, db = _de_call(dy, y, i_max, H, live_tiles(extents, *de_blocks[:2]),
+                      block_b=de_blocks[0], block_s=de_blocks[1],
+                      block_v=de_blocks[2], softcap=softcap,
+                      interpret=interpret)
     return dH, dE, db

@@ -135,10 +135,16 @@ def main(argv=None) -> int:
         # cache, where ops.sparton_head's per-kernel resolution reads
         # them — the config's head_block_* stay unpinned on purpose
         # (pinning would force one joint triple onto all three).
+        # timed on the data's own document mask, so the kernels skip
+        # the tiles they will skip in the run
+        sample = next(lsr_pair_batches(
+            batch=args.batch, q_len=args.seq_len, d_len=args.seq_len,
+            vocab=cfg.vocab_size))
         winners = autotune_kernel_blocks(
             args.batch, args.seq_len, cfg.d_model, cfg.vocab_size,
             dtype=jnp.dtype(cfg.compute_dtype),
-            softcap=cfg.final_logit_softcap)
+            softcap=cfg.final_logit_softcap,
+            mask=jnp.asarray(sample["d_mask"]))
         print(f"autotuned head blocks (B={args.batch} S={args.seq_len} "
               f"D={cfg.d_model} V={cfg.vocab_size}): " +
               ", ".join(f"{kn}={blk}" for kn, blk in winners.items()))

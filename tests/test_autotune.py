@@ -32,14 +32,14 @@ def test_candidates_respect_vmem_budget():
     assert cands, "no candidates under a 12 MiB budget at bert-base size"
     for blocks in cands:
         assert vmem_bytes(blocks, 768) <= budget
-        # Mosaic's (8, 128) tiling: block_b on sublanes, block_s and
-        # block_v on lanes
+        # Mosaic's (8, 128) tiling: block_b and block_s on sublanes (16
+        # holds a packed bf16 tile), block_v on lanes
         assert blocks[0] % 8 == 0
-        assert blocks[1] % 128 == 0
+        assert blocks[1] % 16 == 0
         assert blocks[2] % 128 == 0
     # a short sequence may take its whole padded length as one block
     short = candidate_blocks(4, 40, 64, 1000)
-    assert {bs for _, bs, _ in short} <= {48, 128}
+    assert {bs for _, bs, _ in short} <= {16, 32, 48, 64, 128}
     assert any(bs == 48 for _, bs, _ in short)
 
 
@@ -127,11 +127,16 @@ def test_partial_pin_respects_vmem_budget():
                               pinned=(None, None, 1024))
     assert blocks[2] == 1024
     assert vmem_bytes(blocks, 768) <= autotune.VMEM_BUDGET_BYTES
-    # a pin no free choice can rescue (bv=2048 at D=768 overflows on
-    # the dE scratch alone): minimal free components, not silent drop
+    # bv=2048 fits once block_s may be short
     blocks = heuristic_blocks(320, 512, 768, 250002,
                               pinned=(None, None, 2048))
-    assert blocks == MIN_BLOCKS[:2] + (2048,)
+    assert blocks[2] == 2048
+    assert vmem_bytes(blocks, 768) <= autotune.VMEM_BUDGET_BYTES
+    # a pin no free choice can rescue (bv=4096 at D=768 overflows on
+    # the dE scratch alone): minimal free components, not silent drop
+    blocks = heuristic_blocks(320, 512, 768, 250002,
+                              pinned=(None, None, 4096))
+    assert blocks == MIN_BLOCKS[:2] + (4096,)
     # the kernel-wrapper path must re-enumerate jointly too, not graft
     # the pin onto the unpinned winner
     blocks = resolve_blocks(64, 512, 64, 250002, jnp.float32,
@@ -206,6 +211,42 @@ def test_per_kernel_candidates_admit_more_than_joint():
         assert set(joint) <= set(cands), kn
     assert any(len(cands) > len(joint)
                for cands in per_kernel.values())
+
+
+@pytest.mark.parametrize("B,S,D,V", [(4, 32, 16, 64), (256, 256, 768, 250002),
+                                     (448, 32, 768, 30522)])
+def test_ranked_blocks_lead_with_the_heuristic(B, S, D, V):
+    """One order for every block choice: the heuristic's blocks are the
+    first that the timed tuners time, for each kernel."""
+    for kn in (None,) + autotune.KERNELS:
+        ranked = autotune.ranked_blocks(B, S, D, V, kernel=kn)
+        assert ranked[0] == heuristic_blocks(B, S, D, V, kernel=kn)
+        assert sorted(ranked) == sorted(candidate_blocks(B, S, D, V,
+                                                         kernel=kn))
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+def test_tuners_time_the_heuristic_blocks_on_the_traffic_mask(ragged):
+    """With one candidate the tuners time the heuristic's blocks, on a
+    full mask or on the traffic's ragged one, rows in any order."""
+    from repro.kernels.autotune import autotune_kernel_blocks
+
+    mask = None
+    if ragged:
+        mask = (jnp.arange(32)[None] < jnp.array([9, 32, 0, 17])[:, None]
+                ).astype(jnp.int32)
+    winners = autotune_kernel_blocks(4, 32, 16, 64, max_candidates=1,
+                                     mask=mask)
+    for kn in autotune.KERNELS:
+        assert winners[kn] == heuristic_blocks(4, 32, 16, 64, kernel=kn)
+    assert autotune_blocks(4, 32, 16, 64, max_candidates=1,
+                           mask=mask) == heuristic_blocks(4, 32, 16, 64)
+
+
+def test_tuner_mask_must_have_the_tuned_shape():
+    with pytest.raises(ValueError, match="tuned shape"):
+        autotune_blocks(4, 32, 16, 64, max_candidates=1,
+                        mask=jnp.ones((4, 16), jnp.int32))
 
 
 def test_all_kernel_candidates_failing_does_not_poison_cache(

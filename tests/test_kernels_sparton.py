@@ -6,16 +6,24 @@ inputs against the f32 oracle, the fused backward epilogue (g and db
 computed in-kernel) against both the fused oracle and autograd.
 """
 
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+import bench.traffic
+from bench.traffic import stratified_lengths
+from repro.core.head_api import HeadSpec, make_head
+from repro.core.lm_head import lm_head_naive
 from repro.kernels.ops import sparton_head, sparton_lm_head_kernel
 from repro.kernels.ref import (sparton_backward_fused_ref,
                                sparton_backward_ref, sparton_forward_ref)
-from repro.kernels.sparton import sparton_forward
+from repro.kernels.sparton import (_forward_call, live_tile_share,
+                                   live_tiles, row_extents, sparton_forward)
 from repro.kernels.sparton_bwd import sparton_backward
 
 KEY = jax.random.PRNGKey(0)
@@ -119,8 +127,9 @@ def test_backward_matches_fused_oracle(B, S, D, V, blocks):
     bb, bs, bv = blocks
     y_ref, i_ref = sparton_forward_ref(H, E, b, mask)
     dy = jax.random.normal(jax.random.PRNGKey(9), (B, V))
-    dH, dE, db = sparton_backward(dy, y_ref, i_ref, H, E, block_b=bb,
-                                  block_s=bs, block_v=bv, interpret=True)
+    dH, dE, db = sparton_backward(dy, y_ref, i_ref, H, E, row_extents(mask),
+                                  block_b=bb, block_s=bs, block_v=bv,
+                                  interpret=True)
     dH_ref, dE_ref, db_ref = sparton_backward_fused_ref(
         dy, y_ref, i_ref, H, E)
     np.testing.assert_allclose(np.asarray(dH), np.asarray(dH_ref),
@@ -139,8 +148,9 @@ def test_backward_fused_factor_equals_manual_g():
     y_ref, i_ref = sparton_forward_ref(H, E, b, mask)
     dy = jax.random.normal(jax.random.PRNGKey(17), (B, V))
     g = jnp.where(y_ref > 0, dy * jnp.exp(-y_ref), 0.0)
-    dH, dE, db = sparton_backward(dy, y_ref, i_ref, H, E, block_b=2,
-                                  block_s=32, block_v=64, interpret=True)
+    dH, dE, db = sparton_backward(dy, y_ref, i_ref, H, E, row_extents(mask),
+                                  block_b=2, block_s=32, block_v=64,
+                                  interpret=True)
     dH_ref, dE_ref = sparton_backward_ref(g, i_ref, H, E)
     np.testing.assert_allclose(np.asarray(dH), np.asarray(dH_ref),
                                atol=1e-4, rtol=1e-4)
@@ -255,6 +265,130 @@ def test_kernel_grads_match_lm_head_sparton_autograd():
     for a, c in zip(gk, gj):
         np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                    atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# length-ordered rows and the skipped all-pad sequence tiles
+# ---------------------------------------------------------------------------
+
+# the benchmark's document lengths, and the same scaled to S=40
+with open(os.path.join(os.path.dirname(bench.traffic.__file__), "traffic",
+                       "encode_docs_2560.json")) as f:
+    DOCS = json.load(f)["doc"]
+SMALL_DOCS = dict(DOCS, mean=round(DOCS["mean"] * 40 / DOCS["pad"]),
+                  min=DOCS["min"] * 40 // DOCS["pad"], max=40, pad=40)
+
+
+def _prefix_mask(lengths, S):
+    return jnp.asarray(np.arange(S)[None] < np.asarray(lengths)[:, None],
+                       jnp.int32)
+
+
+def _ordered_mask(case):
+    """(mask, blocks) of one parity case."""
+    rng = np.random.default_rng(3)
+    if case == "stratified":
+        lengths = rng.permutation(stratified_lengths(12, SMALL_DOCS))
+        return _prefix_mask(lengths, 40), (2, 8, 32)
+    if case == "holes":
+        mask = (rng.uniform(size=(12, 40)) > 0.4).astype(np.int32)
+        mask[:, 30:] = 0
+        mask[1, :] = 0
+        mask[2, 5:] = 0
+        return jnp.asarray(mask), (2, 8, 32)
+    if case == "all_pad_row":
+        lengths = rng.permutation(stratified_lengths(12, SMALL_DOCS))
+        lengths[4] = 0
+        return _prefix_mask(lengths, 40), (4, 8, 32)
+    if case == "full":
+        return jnp.ones((12, 40), jnp.int32), (2, 8, 32)
+    # B and S not multiples of block_b and block_s
+    lengths = rng.permutation(stratified_lengths(7, SMALL_DOCS))
+    lengths = np.minimum(lengths, 37)
+    return _prefix_mask(lengths, 37), (2, 16, 32)
+
+
+@pytest.mark.parametrize("case", ["stratified", "holes", "all_pad_row",
+                                  "full", "ragged"])
+def test_length_ordered_head_parity(case):
+    mask, (bb, bs, bv) = _ordered_mask(case)
+    B, S = mask.shape
+    D, V = 16, 64
+    H, E, b, _ = _inputs(B, S, D, V, seed=31)
+    kw = dict(block_b=bb, block_s=bs, block_v=bv, interpret=True)
+
+    # skipping past each row block's extent changes no bit of the
+    # forward, in the caller's order and in length order
+    order = jnp.argsort(-row_extents(mask), stable=True)
+    every_tile = live_tiles(jnp.full((B,), S), bb, bs)
+    for rows in (jnp.arange(B), order):
+        Hr, mr = H[rows], mask[rows]
+        y, i_max = sparton_forward(Hr, E, b, mr, **kw)
+        y_d, i_d = _forward_call(Hr, E, b, mr, every_tile, softcap=None,
+                                 **kw)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(y_d))
+        np.testing.assert_array_equal(np.asarray(i_max), np.asarray(i_d))
+
+    def loss_kernel(H, E, b):
+        y = sparton_head(H, E, b, mask, **kw)
+        return jnp.sum(jnp.sin(y) * jnp.arange(V)), y
+
+    def loss_naive(H, E, b):
+        y = lm_head_naive(H, E, b, mask)
+        return jnp.sum(jnp.sin(y) * jnp.arange(V)), y
+
+    (gk, yk) = jax.grad(loss_kernel, argnums=(0, 1, 2), has_aux=True)(H, E, b)
+    (gn, yn) = jax.grad(loss_naive, argnums=(0, 1, 2), has_aux=True)(H, E, b)
+    np.testing.assert_allclose(np.asarray(yk), np.asarray(yn),
+                               atol=1e-5, rtol=1e-5)
+    for a, c in zip(gk, gn):   # dH, dE, db
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   atol=2e-4, rtol=2e-4)
+
+    # the registry's kernel head hands rows back in the caller's order
+    head = make_head(HeadSpec(impl="kernel", interpret=True))
+    np.testing.assert_allclose(np.asarray(head(H, E, b, mask)),
+                               np.asarray(yn), atol=1e-5, rtol=1e-5)
+
+
+def test_row_extents_and_extent_table_match_brute_force():
+    rng = np.random.default_rng(5)
+    mask = (rng.uniform(size=(13, 45)) > 0.7).astype(np.int32)
+    mask[3] = 0
+    mask[7, 44] = 1
+    ext = np.asarray(row_extents(jnp.asarray(mask)))
+    for r in range(13):
+        real = np.flatnonzero(mask[r])
+        assert ext[r] == (real[-1] + 1 if real.size else 0)
+    for bb, bs in [(1, 1), (2, 8), (4, 16), (8, 32), (16, 64)]:
+        live = np.asarray(live_tiles(ext, bb, bs))
+        n_tiles = -(-45 // bs)
+        for i in range(len(live)):
+            rows = mask[i * bb:(i + 1) * bb]
+            # tiles at or before the block's last real position
+            want = sum(rows[:, k * bs:].any() for k in range(n_tiles))
+            assert live[i] == want, (bb, bs, i)
+
+
+@pytest.mark.parametrize("B,S,bb,bs", [(256, 256, 16, 128), (256, 32, 64, 16),
+                                       (13, 45, 8, 16), (3, 7, 2, 4)])
+def test_full_mask_runs_every_tile(B, S, bb, bs):
+    """A full mask leaves the tile set as it was without skipping."""
+    ext = row_extents(jnp.ones((B, S), jnp.int32))
+    assert live_tile_share(ext, bb, bs, S) == 1.0
+    assert np.all(np.asarray(live_tiles(ext, bb, bs)) == -(-S // bs))
+
+
+@pytest.mark.parametrize("n,bb,bs,share", [(256, 16, 32, 0.383),
+                                           (2560, 64, 32, 0.375),
+                                           (256, 16, 128, 0.562),
+                                           (448, 16, 32, 0.379)])
+def test_live_tile_share_of_the_traffic(n, bb, bs, share):
+    """The document lengths of the benchmark's traffic, longest first:
+    the share of (row block, sequence tile) pairs the kernels run."""
+    ext = np.sort(stratified_lengths(n, DOCS))[::-1]
+    assert live_tile_share(ext, bb, bs, 256) == pytest.approx(share,
+                                                              abs=5e-4)
 
 
 # ---------------------------------------------------------------------------
