@@ -29,7 +29,7 @@ def _seeds(text):
 
 
 def train_readings(cell_of, seeds, control, faults):
-    from bench import compare, faults as flt, reference
+    from bench import backbones, compare, faults as flt
     from bench.drivers import train
     from bench.drivers._common import delete
 
@@ -40,18 +40,19 @@ def train_readings(cell_of, seeds, control, faults):
         return st
 
     jitted = train.build(cell_of(seeds[0]))
+    bb = backbones.load(cell_of(seeds[0]).config)
     half = None
     for seed in seeds:
         cell = cell_of(seed)
         st = program(cell, jitted)
-        ref = reference.train_readings(cell.config, seed, st.batches)
+        ref = bb.train_readings(cell.config, seed, st.batches)
         gaps = compare.leaf_gaps(st.readings["grad"], ref["grad"], ref["grad"])
         print(json.dumps({"seed": seed, "grad_leaf_gaps": gaps,
                           "ref_grad": ref["grad"]}), file=sys.stderr)
         yield seed, "program", compare.train_numbers(st.readings, ref)
         if seed in control:
-            ctrl = reference.train_readings(cell.config, seed, st.batches,
-                                            quant=True)
+            ctrl = bb.train_readings(cell.config, seed, st.batches,
+                                     quant=True)
             yield seed, "control", compare.train_numbers(ctrl, ref)
         if seed in faults:
             if half is None:
@@ -60,7 +61,7 @@ def train_readings(cell_of, seeds, control, faults):
                 from repro.launch import steps
                 hp = cell.config["train"]
                 step = flt.half_batch(steps.build_lsr_train_step)(
-                    train.model_config(cell.config), None, n_micro=1,
+                    bb.program_config(cell.config), None, n_micro=1,
                     n_pairs=cell.traffic["pairs"], lr=hp["lr"],
                     total_steps=hp["total_steps"])
                 half = jax.jit(step, donate_argnums=(0,))
@@ -69,7 +70,7 @@ def train_readings(cell_of, seeds, control, faults):
 
 
 def encode_readings(cell_of, seeds, control, _faults):
-    from bench import compare, reference
+    from bench import backbones, compare
     from bench.drivers import encode
     from bench.drivers._common import CompileCounter, delete, measure
 
@@ -87,12 +88,12 @@ def encode_readings(cell_of, seeds, control, _faults):
         yield seed, "program", encode.check(cell, tokens, mask, values,
                                             indices)
         if seed in control:
+            bb = backbones.load(cell.config)
             block = cell.config["reference"]["rows"]
-            ctrl = reference.encode_readings(cell.config, seed, tokens, mask,
-                                             indices, block=block,
-                                             quant=True)
-            ref = reference.encode_readings(cell.config, seed, tokens, mask,
-                                            ctrl["indices"], block=block)
+            ctrl = bb.encode_readings(cell.config, seed, tokens, mask,
+                                      indices, block=block, quant=True)
+            ref = bb.encode_readings(cell.config, seed, tokens, mask,
+                                     ctrl["indices"], block=block)
             yield seed, "control", compare.encode_numbers(
                 ctrl["values"], ref["at"], ref["values"])
 

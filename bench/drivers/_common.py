@@ -1,6 +1,6 @@
-"""What the drivers share: the program's config from a configuration
-file, the measured window, its optional trace, and the device's peak
-memory."""
+"""What the drivers share: the measured window, its optional trace, and
+the device's peak memory. (What belongs to one architecture, the
+drivers take from its module under ``bench/backbones/``.)"""
 
 from __future__ import annotations
 
@@ -11,12 +11,8 @@ import tempfile
 import time
 from typing import Callable, Dict, Optional, Sequence
 
+from bench import scopes
 from bench import trace as tr
-
-# configuration-file size keys (as published) -> the program's fields
-SIZE_FIELDS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-               "num_attention_heads": "n_heads",
-               "intermediate_size": "d_ff", "vocab_size": "vocab_size"}
 
 
 @dataclasses.dataclass
@@ -46,17 +42,6 @@ class Outcome:
     window_compiles: int
     work: Dict = dataclasses.field(default_factory=dict)
     reduced: Optional[Dict] = None
-
-
-def model_config(config: Dict):
-    """The program's ``TransformerConfig`` for a configuration file."""
-    from repro.configs import get_config
-
-    kw = {field: config[key] for key, field in SIZE_FIELDS.items()}
-    kw["n_kv_heads"] = kw["n_heads"]
-    kw["d_head"] = kw["d_model"] // kw["n_heads"]
-    kw.update(config["run"])
-    return dataclasses.replace(get_config(config["arch"]).CONFIG, **kw)
 
 
 def span(name: str, on: bool):
@@ -96,11 +81,16 @@ class Window:
 def measure(seconds: float, fetch: Callable[[], object],
             dispatch: Callable[[object], object],
             finish: Callable[[object], None], *, sync_label: str,
-            traced: bool, counter: CompileCounter) -> Window:
+            traced: bool, counter: CompileCounter,
+            modules: Optional[Callable[[], Sequence[str]]] = None
+            ) -> Window:
     """Run the window: fetch, dispatch, then finish the previous call, so
     one call is in flight while the host waits; stop once ``seconds``
     have passed and the last call has finished. With ``traced`` the
-    profiler records the window and the trace is reduced."""
+    profiler records the window and the trace is reduced; ``modules``
+    then gives the text of the compiled modules the window ran (from
+    jit's cache, after the window), whose ``op_name``s put the device's
+    ops under the program's scopes."""
     import jax
 
     tmp = tempfile.mkdtemp(prefix="bench_trace_") if traced else None
@@ -130,7 +120,10 @@ def measure(seconds: float, fetch: Callable[[], object],
         if traced:
             jax.profiler.stop_trace()
             pd = tr.load(tr.find_xplane(tmp))
-            reduced = tr.reduce(pd, ("fetch_batch", "dispatch", sync_label))
+            names = (dict(scopes.op_names(t) for t in modules())
+                     if modules is not None else None)
+            reduced = tr.reduce(pd, ("fetch_batch", "dispatch", sync_label),
+                                names)
         return Window(steps, elapsed, compiles, reduced)
     finally:
         if tmp is not None:

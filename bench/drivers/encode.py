@@ -6,7 +6,8 @@ Set-up makes the weights from the seed on the device and encodes one
 batch (which compiles). The window encodes batch after batch. After it,
 a sample of the rows the window returned, drawn from the seed, is
 compared with the plain reference's encoding of the same documents
-(``bench.compare``).
+(``bench.compare``). Weights, reference and work come from the
+configuration's backbone module (``bench/backbones/``).
 """
 
 from __future__ import annotations
@@ -20,9 +21,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import compare, reference, traffic, weights, work
+from bench import backbones, compare, traffic
 from bench.drivers._common import (Cell, CompileCounter, Outcome, delete,
-                                   measure, model_config, peak_bytes)
+                                   measure, peak_bytes)
 
 
 @dataclasses.dataclass
@@ -38,8 +39,10 @@ def start(cell: Cell) -> Started:
     from repro.runtime import serving
 
     V = cell.config["vocab_size"]
-    params = weights.init_params(cell.config, cell.seed)
-    encode = serving.make_config_encoder(params, model_config(cell.config))
+    bb = backbones.load(cell.config)
+    params = bb.init_params(cell.config, cell.seed)
+    encode = serving.make_config_encoder(params,
+                                         bb.program_config(cell.config))
     loader = HostShardedLoader(
         lambda shard, n_shards: traffic.doc_batches(cell.traffic, V,
                                                     cell.seed))
@@ -95,9 +98,9 @@ class Encoded:
 def check(cell: Cell, tokens: np.ndarray, mask: np.ndarray,
           values: np.ndarray, indices: np.ndarray) -> Dict[str, float]:
     """The compared numbers for these rows and the program's reps."""
-    ref = reference.encode_readings(cell.config, cell.seed, tokens, mask,
-                                    indices,
-                                    block=cell.config["reference"]["rows"])
+    ref = backbones.load(cell.config).encode_readings(
+        cell.config, cell.seed, tokens, mask, indices,
+        block=cell.config["reference"]["rows"])
     return compare.encode_numbers(values, ref["at"], ref["values"])
 
 
@@ -108,8 +111,16 @@ def run(cell: Cell) -> Outcome:
     enc = Encoded()
     fetch, dispatch, finish = enc.steps(st)
     setup_s = time.monotonic() - cell.t0
+
+    def modules():
+        b = enc.batches[-1]
+        return [st.encode.func.lower(st.params, jnp.asarray(b["tokens"]),
+                                     jnp.asarray(b["mask"]))
+                .compile().as_text()]
+
     win = measure(cell.seconds, fetch, dispatch, finish,
-                  sync_label="copy_reps", traced=cell.trace, counter=counter)
+                  sync_label="copy_reps", traced=cell.trace, counter=counter,
+                  modules=modules)
     peak = peak_bytes(devices)
     st.loader.close()
     delete(st.params)
@@ -117,13 +128,7 @@ def run(cell: Cell) -> Outcome:
     t_ref = time.monotonic()
     numbers = check(cell, *sampled)
     print(f"reference: {time.monotonic() - t_ref:.1f} s", file=sys.stderr)
-    s = weights.sizes(cell.config)
     lengths = np.concatenate([b["mask"].sum(axis=1) for b in enc.batches])
-    head_fwd = work.Work()
-    for b in enc.batches:
-        B, S = b["mask"].shape
-        head_fwd = head_fwd + work.head_fwd(int(b["mask"].sum()), B, S,
-                                            s["V"], s["D"])
     return Outcome(
         attempted=len(lengths),
         failed=sum(int(np.sum(~np.isfinite(np.asarray(v, np.float32))
@@ -132,6 +137,7 @@ def run(cell: Cell) -> Outcome:
                     "setup_s": setup_s},
         numbers=numbers, memory_peak_bytes=peak,
         window_compiles=win.compiles,
-        work={"head_fwd": head_fwd,
-              "model_flops": work.encode_flops(lengths, s)},
+        work={**backbones.load(cell.config).encode_work(cell.config,
+                                                         enc.batches),
+              "steps": win.steps},
         reduced=win.reduced)

@@ -9,6 +9,8 @@ it and the parameters' change after them are read from the state before
 the window starts, and the same object runs on into the window. After
 the window, with the program's state freed, the plain reference follows
 the same first steps from the same seeded weights (``bench.compare``).
+Weights, reference and work come from the configuration's backbone
+module (``bench/backbones/``).
 """
 
 from __future__ import annotations
@@ -24,9 +26,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import compare, reference, traffic, weights, work
+from bench import backbones, compare, reference, traffic
 from bench.drivers._common import (Cell, CompileCounter, Outcome, delete,
-                                   measure, model_config, peak_bytes)
+                                   measure, peak_bytes)
 
 # steps the reference follows, from the first: two, so that the plain
 # reference at the cells' batches takes about as long as the window
@@ -62,7 +64,8 @@ def build(cell: Cell):
     from repro.launch.steps import build_lsr_train_step
 
     hp = cell.config["train"]
-    step = build_lsr_train_step(model_config(cell.config), None, n_micro=1,
+    cfg = backbones.load(cell.config).program_config(cell.config)
+    step = build_lsr_train_step(cfg, None, n_micro=1,
                                 n_pairs=cell.traffic["pairs"], lr=hp["lr"],
                                 total_steps=hp["total_steps"])
     return jax.jit(step, donate_argnums=(0,))
@@ -73,7 +76,8 @@ def start(cell: Cell, jitted) -> Started:
     from repro.data.loader import HostShardedLoader
 
     hp, V = cell.config["train"], cell.config["vocab_size"]
-    state = weights.init_state(cell.config, cell.seed)
+    bb = backbones.load(cell.config)
+    state = bb.init_state(cell.config, cell.seed)
     loader = HostShardedLoader(
         lambda shard, n_shards: traffic.pair_batches(cell.traffic, V,
                                                      cell.seed))
@@ -86,31 +90,16 @@ def start(cell: Cell, jitted) -> Started:
         if grad is None:
             grad = np.asarray(_first_grad(state["opt"]["mu"], b1=hp["b1"]))
     names = reference.leaf_names(state["params"])
-    change = reference.change_norms(state["params"], cell.config, cell.seed)
+    change = bb.change_norms(state["params"], cell.config, cell.seed)
     readings = {"loss": losses, "grad": dict(zip(names, grad.tolist())),
                 "change": dict(zip(names, change.tolist()))}
     return Started(jitted, state, loader, batches, readings)
 
 
-def window_work(cell: Cell, batch: Dict[str, np.ndarray]) -> Dict:
-    """Work of one step: the head's kernels and the model FLOPs."""
-    s = weights.sizes(cell.config)
-    V, D = s["V"], s["D"]
-    fwd = dh = de = work.Work()
-    for tok, mask in (("q_tokens", "q_mask"), ("d_tokens", "d_mask")):
-        B, S = batch[tok].shape
-        fwd = fwd + work.head_fwd(int(batch[mask].sum()), B, S, V, D)
-        dh = dh + work.head_dh(B, S, V, D)
-        de = de + work.head_de(B, S, V, D)
-    flops = work.train_step_flops(batch["q_mask"].sum(1),
-                                  batch["d_mask"].sum(1), s)
-    return {"head_fwd": fwd, "head_dh": dh, "head_de": de,
-            "model_flops": flops}
-
-
 def run(cell: Cell) -> Outcome:
     counter = CompileCounter()
     devices = jax.devices()[:cell.chips]
+    bb = backbones.load(cell.config)
     st = start(cell, build(cell))
     tokens, failed, one_batch = [0], [0], {}
 
@@ -129,15 +118,22 @@ def run(cell: Cell) -> Outcome:
             failed[0] += 1
 
     setup_s = time.monotonic() - cell.t0
+
+    def modules():
+        # every batch has the checked steps' shapes
+        return [st.step.lower(st.state, _place(st.batches[0])).compile()
+                .as_text()]
+
     win = measure(cell.seconds, fetch, dispatch, finish,
-                  sync_label="sync_loss", traced=cell.trace, counter=counter)
+                  sync_label="sync_loss", traced=cell.trace, counter=counter,
+                  modules=modules)
     peak = peak_bytes(devices)
     st.loader.close()
     delete(st.state)
     t_ref = time.monotonic()
-    ref = reference.train_readings(cell.config, cell.seed, st.batches)
+    ref = bb.train_readings(cell.config, cell.seed, st.batches)
     print(f"reference: {time.monotonic() - t_ref:.1f} s", file=sys.stderr)
-    per_step = window_work(cell, one_batch["b"])
+    per_step = bb.step_work(cell.config, one_batch["b"])
     return Outcome(
         attempted=win.steps, failed=failed[0],
         end_to_end={"train_tokens_per_s": tokens[0] / win.seconds,
@@ -145,5 +141,6 @@ def run(cell: Cell) -> Outcome:
                     "setup_s": setup_s},
         numbers=compare.train_numbers(st.readings, ref),
         memory_peak_bytes=peak, window_compiles=win.compiles,
-        work={k: v * win.steps for k, v in per_step.items()},
+        work={**{k: v * win.steps for k, v in per_step.items()},
+              "steps": win.steps},
         reduced=win.reduced)

@@ -1,8 +1,9 @@
 """Arithmetic the per-layer readers share. A reader takes ``ctx``:
-``reduced`` (``bench.trace.reduce`` of the traced window), ``work``
-(the driver's counts for that window), ``peaks`` (the chip's row of
-``bench/peaks.json``) and ``chips``. It returns None where the trace
-holds nothing to read."""
+``reduced`` (``bench.trace.reduce`` of the traced window, with the
+program's ``scope_seconds`` and ``span_seconds``), ``work`` (the
+driver's counts for that window, ``steps`` among them: the steps or
+batches it ran), ``peaks`` (the chip's row of ``bench/peaks.json``) and
+``chips``. It returns None where the trace holds nothing to read."""
 
 from bench import trace
 
@@ -36,3 +37,23 @@ def idle(ctx):
     """Share (%) of the window in which no operation ran on the device."""
     red = ctx["reduced"]
     return 100.0 * (1.0 - red["busy_s"] / red["window_s"])
+
+
+def _per_step_ms(ctx, seconds):
+    if not seconds:
+        return None
+    return 1000.0 * seconds / ctx["work"]["steps"]
+
+
+def scope_ms(ctx, scope):
+    """Device time (ms) a step or batch under the program's named
+    ``scope`` (``bench.scopes``); None where the scope reads 0."""
+    return _per_step_ms(ctx, ctx["reduced"].get("scope_seconds", {})
+                        .get(scope))
+
+
+def span_ms(ctx, span):
+    """Time (ms) a step or batch in the program's host ``span``; None
+    where it reads 0."""
+    return _per_step_ms(ctx, ctx["reduced"].get("span_seconds", {})
+                        .get(span))

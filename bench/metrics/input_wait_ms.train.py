@@ -1,0 +1,8 @@
+"""Host time a training step waiting for input
+(``data/loader.HostShardedLoader``, span ``loader.next``)."""
+
+from bench.metrics import _shared
+
+
+def read(ctx):
+    return _shared.span_ms(ctx, "loader.next")

@@ -7,7 +7,8 @@ Everything about a cell is found by name: its entry in ``BENCHMARK.json``
 names a configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``, which names its driver,
 ``bench/drivers/<driver>.py``); its limits are in
-``bench/limits/<cell>.json``; each per-layer metric is read by
+``bench/limits/<cell>.json``; the configuration names its backbone
+(``bench/backbones/<backbone>.py``); each per-layer metric is read by
 ``bench/metrics/<metric>.py``. The last line of standard output is one
 JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
 ``device`` (and with ``--trace 1`` a ``breakdown``), then ``checks``,
@@ -140,6 +141,11 @@ def main(argv=None) -> int:
     setup_compile_cache()
     # every program, however quick to compile, goes to the cache
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    if args.trace:
+        # the scopes are read from the executables' op_name metadata,
+        # which the cache's key otherwise leaves out
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     devices = jax.devices()
     chips = spec["cell"]["chips"]
     if devices[0].platform != "tpu" or len(devices) < chips:

@@ -1,37 +1,38 @@
 """Layer time from the program's named scopes and host spans in a
 profiler trace.
 
-The program names its layers with ``jax.named_scope``: ``backbone``
-(``models/transformer.forward_hidden``), ``optimizer`` (the update in
-``launch/steps.build_lsr_train_step``) and ``sparsify`` (the rep
-sparsifier in ``core/head_api.make_encoder``). A scope reaches the
-compiled module as the ``op_name`` metadata of each instruction
+The program names its layers with ``jax.named_scope`` (``backbone`` in
+``models/transformer.forward_hidden``, ``optimizer`` in
+``launch/steps.build_lsr_train_step``, ``sparsify`` in
+``core/head_api.make_encoder``, ``head_order`` in ``kernels/ops.py``,
+and whatever scope a later change adds). A scope reaches the compiled
+module as the ``op_name`` metadata of each instruction
 (``jit(step)/transpose(jvp(backbone))/while``), and the device trace
 names each op by its instruction (``bench.trace.op_name``). So the map
 from instruction to ``op_name`` that ``op_names`` parses out of the
 module's text (``Compiled.as_text()``) attributes each device op to its
-scopes. Instruction names such as ``fusion.12`` repeat across modules:
-an op is looked up in the module whose event on the device's
-``XLA Modules`` line contains it.
+scopes: every component of its ``op_name`` but the last, which is the
+operation itself (so the jitted function's own name and those of the
+functions it calls count as scopes too). Instruction names such as
+``fusion.12`` repeat across modules: an op is looked up in the module
+whose event on the device's ``XLA Modules`` line contains it.
 
 A scope's time is the union of its ops' intervals, clipped to the
 window, so that a ``while`` and the ops of its body count once; a host
 span's time (``loader.next``, the program's wait for input in
-``data/loader.HostShardedLoader``) is the union of its intervals in the
-window. Device times are averaged over the devices that ran, as in
-``bench.trace.reduce``.
+``data/loader.HostShardedLoader``, or any other) is the union of its
+intervals in the window. Device times are averaged over the devices that
+ran, as in ``bench.trace.reduce``, which calls both.
 """
 
 from __future__ import annotations
 
 import bisect
 import re
-from typing import Dict, Iterable, List, Mapping, Sequence, Tuple
+from typing import Dict, Iterable, List, Mapping, Tuple
 
 from bench import trace
 
-SCOPES = ("backbone", "optimizer", "sparsify")
-SPANS = ("loader.next",)
 MODULES_LINE = "XLA Modules"
 
 _MODULE = re.compile(r"^HloModule\s+([^\s,]+)", re.M)
@@ -103,38 +104,38 @@ def _device_lines(pd):
             yield ops, mods
 
 
-def scope_seconds(pd, modules: Mapping[str, Mapping[str, str]],
-                  scopes: Sequence[str] = SCOPES) -> Dict[str, float]:
-    """Seconds of device time in the window under each scope, averaged
-    over the devices that ran. ``modules`` maps a module name to its
-    ``op_names``; ops of other modules belong to no scope."""
+def scope_seconds(pd, modules: Mapping[str, Mapping[str, str]]
+                  ) -> Dict[str, float]:
+    """Seconds of device time in the window under each scope the ops'
+    ``op_name``s carry, averaged over the devices that ran. ``modules``
+    maps a module name to its ``op_names``; ops of other modules belong
+    to no scope."""
     lo, hi = _window(pd)
-    total = dict.fromkeys(scopes, 0.0)
+    total: Dict[str, float] = {}
+    of: Dict[Tuple[str, str], frozenset] = {}
     n = 0
     for ops, mods in _device_lines(pd):
         n += 1
         starts = [a for _, a, _ in mods]
-        per: Dict[str, list] = {s: [] for s in scopes}
+        per: Dict[str, list] = {}
         for name, a, b in ops:
             i = bisect.bisect_right(starts, a) - 1
             if i < 0 or a > mods[i][2]:
                 continue
-            op = modules.get(mods[i][0], {}).get(name)
-            if op is None:
-                continue
-            parts = scopes_of(op)
-            for s in scopes:
-                if s in parts:
-                    per[s].append((a, b))
+            key = (mods[i][0], name)
+            if key not in of:
+                op = modules.get(key[0], {}).get(name)
+                of[key] = frozenset(scopes_of(op)[:-1] if op else ())
+            for s in of[key]:
+                per.setdefault(s, []).append((a, b))
         for s, iv in per.items():
-            total[s] += trace.length(_clip(iv, lo, hi))
+            total[s] = total.get(s, 0.0) + trace.length(_clip(iv, lo, hi))
     return {s: v * 1e-9 / max(n, 1) for s, v in total.items()}
 
 
-def span_seconds(pd, names: Sequence[str] = SPANS) -> Dict[str, float]:
+def span_seconds(pd) -> Dict[str, float]:
     """Seconds of each host span in the window (the union of its
-    intervals)."""
+    intervals), for every span name the trace holds."""
     lo, hi = _window(pd)
-    spans = trace.host_spans(pd)
-    return {n: trace.length(_clip(spans.get(n, ()), lo, hi)) * 1e-9
-            for n in names}
+    return {n: trace.length(_clip(iv, lo, hi)) * 1e-9
+            for n, iv in trace.host_spans(pd).items()}

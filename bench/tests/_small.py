@@ -1,11 +1,13 @@
 """A cell at a size a CPU test run holds: the published configuration
-files with their widths cut, and short traffic."""
+files with their sizes cut as their backbone's ``SMALL`` says, and short
+traffic."""
 
 import copy
 import json
 import os
 import time
 
+from bench import backbones
 from bench.drivers._common import Cell
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -20,8 +22,7 @@ def load(kind, name):
 
 def config(name="splade_bert"):
     cfg = copy.deepcopy(load("configs", name))
-    cfg.update(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
-               intermediate_size=128, vocab_size=512)
+    cfg.update(backbones.load(cfg).SMALL)
     cfg["run"]["rep_topk"] = 16
     cfg["init"]["head_bias"] = -1.0
     cfg["reference"] = {"vocab_tile": 128, "rows": 8}
@@ -38,12 +39,12 @@ LIMITS = {"train": {"loss_gap": 0.06, "grad_norm_gap": 0.1,
           "encode": {"rep_value_gap": 0.06, "rep_topk_gap": 0.06}}
 
 
-def cell(driver, seed=2 ** 40 + 7, seconds=0.5):
+def cell(driver, seed=2 ** 40 + 7, seconds=0.5, name="splade_bert"):
     if driver == "train":
         traffic = {"driver": "train", "pairs": 8, "query": QUERY, "doc": DOC}
     else:
         traffic = {"driver": "encode", "docs": 8, "doc": DOC,
                    "check_rows": 16}
-    return Cell(name="small", config=config(), traffic=traffic, chips=1,
+    return Cell(name="small", config=config(name), traffic=traffic, chips=1,
                 seed=seed, seconds=seconds, trace=False, t0=time.monotonic(),
                 limits=LIMITS[driver])

@@ -5,7 +5,7 @@ it; here at a size a test run holds.)"""
 
 import numpy as np
 
-from bench import compare, reference, traffic
+from bench import backbones, compare, traffic
 from bench.tests import _small
 
 
@@ -14,9 +14,9 @@ def test_float8_training_is_not_correct():
     it = traffic.pair_batches(cell.traffic, cell.config["vocab_size"],
                               cell.seed)
     batches = [next(it) for _ in range(3)]
-    ref = reference.train_readings(cell.config, cell.seed, batches)
-    ctrl = reference.train_readings(cell.config, cell.seed, batches,
-                                    quant=True)
+    bb = backbones.load(cell.config)
+    ref = bb.train_readings(cell.config, cell.seed, batches)
+    ctrl = bb.train_readings(cell.config, cell.seed, batches, quant=True)
     numbers = compare.train_numbers(ctrl, ref)
     assert not compare.passed(compare.checks(numbers, cell.limits))
 
@@ -27,9 +27,10 @@ def test_float8_encoding_is_not_correct():
                                  cell.config["vocab_size"], cell.seed))
     k = cell.config["run"]["rep_topk"]
     dummy = np.zeros((16, k), np.int32)
-    ctrl = reference.encode_readings(cell.config, cell.seed, b["tokens"],
-                                     b["mask"], dummy, block=8, quant=True)
-    ref = reference.encode_readings(cell.config, cell.seed, b["tokens"],
-                                    b["mask"], ctrl["indices"], block=8)
+    bb = backbones.load(cell.config)
+    ctrl = bb.encode_readings(cell.config, cell.seed, b["tokens"], b["mask"],
+                              dummy, block=8, quant=True)
+    ref = bb.encode_readings(cell.config, cell.seed, b["tokens"], b["mask"],
+                             ctrl["indices"], block=8)
     numbers = compare.encode_numbers(ctrl["values"], ref["at"], ref["values"])
     assert not compare.passed(compare.checks(numbers, cell.limits))

@@ -8,8 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import reference, traffic, weights
-from bench.drivers._common import model_config
+from bench import reference, traffic
+from bench.backbones import dense_encoder as dense
 from bench.tests import _small
 
 CFG = _small.config()
@@ -25,13 +25,14 @@ def _batch():
 def test_backbone_matches_the_program_in_float32():
     from repro.models import transformer as tfm
 
-    params = weights.init_params(CFG, 5)
+    params = dense.init_params(CFG, 5)
     b = _batch()
     tok, mask = jnp.asarray(b["tokens"]), jnp.asarray(b["mask"])
-    cfg = dataclasses.replace(model_config(CFG), compute_dtype="float32")
+    cfg = dataclasses.replace(dense.program_config(CFG),
+                              compute_dtype="float32")
     with jax.default_matmul_precision("highest"):
         prog, _ = tfm.forward_hidden(params, cfg, tok, mask)
-    ref = reference.hidden(params, tok, mask, quant=False, **KW)
+    ref = dense.hidden(params, tok, mask, quant=False, **KW)
     keep = np.asarray(b["mask"], bool)
     np.testing.assert_allclose(np.asarray(ref)[keep], np.asarray(prog)[keep],
                                rtol=2e-4, atol=2e-4)
@@ -63,10 +64,10 @@ def test_blocked_head_and_its_gradient_match_the_dense_head():
 
 
 def test_float8_control_departs_from_the_reference():
-    params = weights.init_params(CFG, 5)
+    params = dense.init_params(CFG, 5)
     b = _batch()
     tok, mask = jnp.asarray(b["tokens"]), jnp.asarray(b["mask"])
-    full = reference.hidden(params, tok, mask, quant=False, **KW)
-    low = reference.hidden(params, tok, mask, quant=True, **KW)
+    full = dense.hidden(params, tok, mask, quant=False, **KW)
+    low = dense.hidden(params, tok, mask, quant=True, **KW)
     rel = float(jnp.linalg.norm(low - full) / jnp.linalg.norm(full))
     assert 1e-3 < rel < 0.5

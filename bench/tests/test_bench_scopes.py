@@ -1,7 +1,8 @@
 """Layer time from the program's named scopes and host spans: the
 parser of a compiled module's ``op_name``s, the union per scope on a
 synthetic trace, the scopes in the small step's and encoder's compiled
-modules, and a small trace recorded on a TPU v5e
+modules, the readers of ``bench/metrics/`` that take them, and a small
+trace recorded on a TPU v5e
 (``bench/testdata/scopes.xplane.pb`` with ``scopes.op_names.json``,
 made by ``record_scopes.py``: three training steps and three encoded
 batches at ``_small.py``'s sizes)."""
@@ -13,10 +14,12 @@ import types
 import jax
 import pytest
 
-from bench import scopes, trace
+from bench import run, scopes, trace
 from bench.tests import _small
 
 TESTDATA = os.path.join(_small.BENCH, "testdata")
+# the scopes the program names (PERF.md, section 3)
+SCOPES = ("backbone", "optimizer", "sparsify", "head_order")
 
 HLO = """HloModule jit_step, is_scheduled=true, entry_computation_layout={()}
 
@@ -108,59 +111,112 @@ def test_scope_union_on_a_synthetic_trace():
     # backbone: [100, 500] from 200 on; optimizer: [1000, 1200]
     assert got["backbone"] == pytest.approx(300 * ns)
     assert got["optimizer"] == pytest.approx(200 * ns)
-    assert got["sparsify"] == 0.0
+    assert "sparsify" not in got
+    # every component but the operation is a scope: the jitted function
+    # and the ones it calls too
+    assert got["step"] == pytest.approx((300 + 100 + 200) * ns)
+    assert got["_forward_call"] == pytest.approx(100 * ns)
+    assert got["other"] == pytest.approx(30 * ns)
+    assert not {"sub", "dot", "x", "add"} & set(got)
     # averaged over the devices, as busy time is
     assert scopes.scope_seconds(_synthetic((0,)), MODULES) == got
     # a module not in the map contributes nothing
     mods = {"jit_other": MODULES["jit_other"]}
-    assert scopes.scope_seconds(_synthetic(), mods)["backbone"] == 0.0
+    assert "backbone" not in scopes.scope_seconds(_synthetic(), mods)
 
 
 def test_span_seconds_clipped_to_the_window():
-    got = scopes.span_seconds(_synthetic(), ("loader.next", "absent"))
+    got = scopes.span_seconds(_synthetic())
     assert got["loader.next"] == pytest.approx((40 + 20) * 1e-9)
-    assert got["absent"] == 0.0
+    assert got["fetch_batch"] == pytest.approx((50 + 50) * 1e-9)
+    assert "absent" not in got
+
+
+def test_reduce_adds_scopes_and_spans_given_the_modules():
+    pd = _synthetic()
+    red = trace.reduce(pd, ("fetch_batch",), MODULES)
+    assert red["scope_seconds"] == scopes.scope_seconds(pd, MODULES)
+    assert red["span_seconds"] == scopes.span_seconds(pd)
+    plain = trace.reduce(pd, ("fetch_batch",))
+    assert "scope_seconds" not in plain and "span_seconds" not in plain
+    assert plain == {k: v for k, v in red.items()
+                     if k not in ("scope_seconds", "span_seconds")}
+
+
+READERS = {"backbone_ms.train": "backbone", "backbone_ms.encode": "backbone",
+           "optimizer_ms.train": "optimizer",
+           "sparsify_ms.encode": "sparsify",
+           "head_order_ms.train": "head_order",
+           "head_order_ms.encode": "head_order"}
+SPAN_READERS = {"input_wait_ms.train": "loader.next",
+                "input_wait_ms.encode": "loader.next"}
+
+
+def _read(metric, reduced, steps):
+    reader = run._load_file(os.path.join(_small.BENCH, "metrics",
+                                         metric + ".py"))
+    return reader.read({"reduced": reduced, "work": {"steps": steps},
+                        "peaks": {}, "chips": 1})
+
+
+@pytest.mark.parametrize("metric", sorted(READERS) + sorted(SPAN_READERS))
+def test_scope_reader_on_a_synthetic_trace(metric):
+    red = trace.reduce(_synthetic(), ("fetch_batch",), MODULES)
+    if metric in SPAN_READERS:
+        expected = 1000 * red["span_seconds"][SPAN_READERS[metric]] / 4
+    else:
+        expected = red["scope_seconds"].get(READERS[metric], 0) * 1000 / 4
+    got = _read(metric, red, 4)
+    # a scope that reads 0 gives nothing, never a 0
+    assert got == (pytest.approx(expected) if expected else None)
+    # nor does a trace reduced without the modules
+    assert _read(metric, trace.reduce(_synthetic(), ()), 4) is None
 
 
 @pytest.mark.parametrize("driver,expected", [
-    ("train", ("backbone", "optimizer")),
-    ("encode", ("backbone", "sparsify"))])
+    ("train", ("backbone", "optimizer", "head_order")),
+    ("encode", ("backbone", "sparsify", "head_order"))])
 def test_compiled_modules_carry_the_scopes(driver, expected):
-    from bench import traffic, weights
+    from bench import backbones, traffic
     from bench.drivers import train
-    from bench.drivers._common import model_config
     from repro.runtime import serving
 
     cell = _small.cell(driver)
+    bb = backbones.load(cell.config)
     V = cell.config["vocab_size"]
     if driver == "train":
         state = jax.eval_shape(
-            lambda: weights.init_state(cell.config, cell.seed))
+            lambda: bb.init_state(cell.config, cell.seed))
         batch = next(traffic.pair_batches(cell.traffic, V, cell.seed))
         lowered = train.build(cell).lower(state, batch)
     else:
         params = jax.eval_shape(
-            lambda: weights.init_params(cell.config, cell.seed))
+            lambda: bb.init_params(cell.config, cell.seed))
         docs = next(traffic.doc_batches(cell.traffic, V, cell.seed))
         encode = serving.make_config_encoder(params,
-                                             model_config(cell.config))
+                                             bb.program_config(cell.config))
         lowered = encode.func.lower(params, docs["tokens"], docs["mask"])
     module, names = scopes.op_names(lowered.compile().as_text())
     assert module == f"jit_{'step' if driver == 'train' else 'encode'}"
     found = {s for n in names.values() for s in scopes.scopes_of(n)}
     assert set(expected) <= found
-    assert not set(scopes.SCOPES) - set(expected) & found
+    assert not set(SCOPES) - set(expected) & found
 
 
-def test_recorded_chip_trace():
+def _recorded():
     pd = trace.load(os.path.join(TESTDATA, "scopes.xplane.pb"))
     with open(os.path.join(TESTDATA, "scopes.op_names.json")) as f:
         modules = json.load(f)
+    return pd, modules, trace.reduce(pd, ("fetch_batch", "dispatch",
+                                          "sync_loss", "copy_reps"), modules)
+
+
+def test_recorded_chip_trace():
+    pd, modules, red = _recorded()
     assert set(modules) == {"jit_step", "jit_encode"}
-    red = trace.reduce(pd, ("fetch_batch", "dispatch", "sync_loss",
-                            "copy_reps"))
-    got = scopes.scope_seconds(pd, modules)
-    for s in scopes.SCOPES:
+    got = red["scope_seconds"]
+    assert got == scopes.scope_seconds(pd, modules)
+    for s in SCOPES:
         assert 0 < got[s] <= red["busy_s"]
     # the kernels keep the names the roofline readers match, and lie
     # outside every scope
@@ -171,15 +227,27 @@ def test_recorded_chip_trace():
                if kernel in k]
         assert ops
         for m, k in ops:
-            assert not set(scopes.SCOPES) & set(
-                scopes.scopes_of(modules[m][k]))
+            assert not set(SCOPES) & set(scopes.scopes_of(modules[m][k]))
     # the program's span shares the device trace's clock: each wait for
     # input lies inside the harness's fetch_batch around it
     spans = trace.host_spans(pd)
     assert len(spans["loader.next"]) == 6
     for a, b in spans["loader.next"]:
         assert any(fa <= a and b <= fb for fa, fb in spans["fetch_batch"])
-    assert 0 < scopes.span_seconds(pd)["loader.next"] < red["window_s"]
+    assert 0 < red["span_seconds"]["loader.next"] < red["window_s"]
+
+
+@pytest.mark.parametrize("metric", sorted(READERS) + sorted(SPAN_READERS))
+def test_scope_reader_on_the_recorded_chip_trace(metric):
+    # three training steps and three encoded batches in one window
+    _, _, red = _recorded()
+    got = _read(metric, red, 6)
+    if metric in SPAN_READERS:
+        seconds = red["span_seconds"][SPAN_READERS[metric]]
+    else:
+        seconds = red["scope_seconds"][READERS[metric]]
+    assert got == pytest.approx(1000 * seconds / 6)
+    assert 0 < got < 1000 * red["window_s"] / 6
 
 
 def test_recorder_drops_only_the_named_plane():

@@ -3,6 +3,7 @@
 import pytest
 
 from bench import work
+from bench.backbones import dense_encoder as dense
 
 PEAK = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
 
@@ -42,7 +43,7 @@ SIZES = {"L": 2, "D": 4, "H": 2, "dh": 2, "F": 8, "V": 10}
 
 def test_transformer_params():
     # per layer 4 * 4*2*2 attention + 3 * 4*8 FFN
-    assert work.transformer_params(SIZES) == 2 * (64 + 96)
+    assert dense.transformer_params(SIZES) == 2 * (64 + 96)
 
 
 def test_encode_and_train_flops():
@@ -50,7 +51,7 @@ def test_encode_and_train_flops():
     # lengths 1 and 3: T = 4, sum n^2 = 10
     attn = 4 * 10 * 2 * 2 * 2
     enc = 2 * P * 4 + attn + 2 * 4 * 10 * 4
-    assert work.encode_flops([1, 3], SIZES) == enc
+    assert dense.encode_flops([1, 3], SIZES) == enc
     q = 6 * P * 4 + 3 * attn + 2 * 4 * 10 * 4 + 4 * 2 * 10 * 4
     d = 6 * P * 2 + 3 * (4 * 2 * 2 * 2 * 2) + 2 * 2 * 10 * 4 + 4 * 2 * 10 * 4
-    assert work.train_step_flops([1, 3], [1, 1], SIZES) == q + d + 6 * 4 * 10
+    assert dense.train_step_flops([1, 3], [1, 1], SIZES) == q + d + 6 * 4 * 10

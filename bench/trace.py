@@ -10,7 +10,10 @@ reduction reads, for each device plane:
 * time per operation name (kernel time is the sum over a kernel's
   events);
 * idle time: the window less the busy union, attributed to the host
-  span it falls in, or to ``other`` where no span covers it.
+  span it falls in, or to ``other`` where no span covers it;
+* given the compiled modules' maps from instruction to ``op_name``, the
+  device time under each of the program's named scopes and the time of
+  each host span (``bench.scopes``).
 
 Times are averaged over the devices that ran anything. Reading needs
 only ``jax.profiler.ProfileData``; nothing here touches a device.
@@ -20,7 +23,7 @@ from __future__ import annotations
 
 import glob
 import os
-from typing import Dict, Iterable, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 
 Interval = Tuple[float, float]
 
@@ -131,9 +134,13 @@ def device_ops(pd) -> Dict[str, List[Tuple[str, float, float]]]:
     return out
 
 
-def reduce(pd, labels: Sequence[str]) -> Dict:
+def reduce(pd, labels: Sequence[str],
+           modules: Optional[Mapping[str, Mapping[str, str]]] = None
+           ) -> Dict:
     """Window, busy time, time per device operation and idle time by
-    host span, in seconds, averaged over the devices that ran."""
+    host span, in seconds, averaged over the devices that ran. With
+    ``modules`` (module name -> ``bench.scopes.op_names``' map) also
+    ``scope_seconds`` and ``span_seconds``."""
     spans = host_spans(pd)
     if WINDOW not in spans:
         raise ValueError(f"the trace has no host span named {WINDOW!r}")
@@ -163,11 +170,17 @@ def reduce(pd, labels: Sequence[str]) -> Dict:
             left -= t
         idle[OTHER] = idle.get(OTHER, 0.0) + max(left, 0.0)
     ns = 1e-9 / n
-    return {"window_s": (hi - lo) * 1e-9, "busy_s": busy * ns,
-            "n_devices": n,
-            "op_seconds": {k: v * ns for k, v in op_s.items()},
-            "op_counts": {k: v / n for k, v in op_n.items()},
-            "idle_seconds": {k: v * ns for k, v in idle.items()}}
+    out = {"window_s": (hi - lo) * 1e-9, "busy_s": busy * ns,
+           "n_devices": n,
+           "op_seconds": {k: v * ns for k, v in op_s.items()},
+           "op_counts": {k: v / n for k, v in op_n.items()},
+           "idle_seconds": {k: v * ns for k, v in idle.items()}}
+    if modules is not None:
+        from bench import scopes
+
+        out["scope_seconds"] = scopes.scope_seconds(pd, modules)
+        out["span_seconds"] = scopes.span_seconds(pd)
+    return out
 
 
 def kernel_seconds(reduced: Dict, pattern: str) -> float:

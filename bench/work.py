@@ -1,4 +1,6 @@
-"""The work the math needs, from shapes and masks, and the chip's peaks.
+"""The work the Sparton head needs, from shapes and masks, and the
+chip's peaks. (Each backbone counts its own model FLOPs, in its module
+under ``bench/backbones/``.)
 
 Counts are of the work the operation needs, whatever implements it, so
 a share of a peak computed from them is a lower bound on the time and
@@ -10,11 +12,6 @@ cannot pass 100%:
 * Head backward, per encoder call of ``B`` sequences: the arg-max
   gradient routes one row per (b, v), so ``2 * B * V * D`` for dH and as
   many for dE.
-* Model FLOPs of a training step: ``6 * P * T`` for the transformer's
-  matrices (P parameters, T real tokens), attention over each
-  sequence's real length (``4 * n^2 * D`` a layer forward, three times
-  that with the backward), the head forward and the backward above, and
-  the in-batch score matrix of InfoNCE. Recomputation does not count.
 """
 
 from __future__ import annotations
@@ -22,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Dict, Sequence
+from typing import Dict, Sequence, Tuple
 
 import numpy as np
 
@@ -83,33 +80,24 @@ def head_de(B: int, S: int, V: int, D: int, act_bytes: int = BF16) -> Work:
                       + V * D * F32 + V * F32))
 
 
-def transformer_params(sizes: Dict) -> int:
-    """Matrix parameters of the layers (the norms' scales excluded)."""
-    L, D, H, dh, F = (sizes[k] for k in ("L", "D", "H", "dh", "F"))
-    return L * (4 * D * H * dh + 3 * D * F)
+def head_train(batch: Dict[str, np.ndarray], V: int, D: int
+               ) -> Tuple[Work, Work, Work]:
+    """The head's forward, dH and dE work in one training step: one
+    encoder call for the queries and one for the documents."""
+    fwd = dh = de = Work()
+    for tok, mask in (("q_tokens", "q_mask"), ("d_tokens", "d_mask")):
+        B, S = batch[tok].shape
+        fwd = fwd + head_fwd(int(batch[mask].sum()), B, S, V, D)
+        dh = dh + head_dh(B, S, V, D)
+        de = de + head_de(B, S, V, D)
+    return fwd, dh, de
 
 
-def _attention_fwd(lengths: Sequence[int], sizes: Dict) -> float:
-    n2 = float(np.sum(np.square(np.asarray(lengths, np.float64))))
-    return 4.0 * n2 * sizes["H"] * sizes["dh"] * sizes["L"]
-
-
-def encode_flops(lengths: Sequence[int], sizes: Dict) -> float:
-    """Model FLOPs of encoding sequences of these real lengths."""
-    T = float(np.sum(lengths))
-    return (2.0 * transformer_params(sizes) * T
-            + _attention_fwd(lengths, sizes)
-            + 2.0 * T * sizes["V"] * sizes["D"])
-
-
-def train_step_flops(q_lengths: Sequence[int], d_lengths: Sequence[int],
-                     sizes: Dict) -> float:
-    """Model FLOPs of one (query, document) contrastive step."""
-    P, V, D = transformer_params(sizes), sizes["V"], sizes["D"]
-    total = 0.0
-    for lengths in (q_lengths, d_lengths):
-        T = float(np.sum(lengths))
-        total += 6.0 * P * T + 3.0 * _attention_fwd(lengths, sizes)
-        total += 2.0 * T * V * D + 4.0 * len(lengths) * V * D
-    B = len(q_lengths)
-    return total + 6.0 * B * B * V
+def head_encode(batches: Sequence[Dict[str, np.ndarray]], V: int, D: int
+                ) -> Work:
+    """The head's forward work over encoded document batches."""
+    total = Work()
+    for b in batches:
+        B, S = b["mask"].shape
+        total = total + head_fwd(int(b["mask"].sum()), B, S, V, D)
+    return total
