@@ -52,6 +52,27 @@ class TransformerConfig:
     n_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    # "softmax": top-k of softmax probabilities, capacity-dropping
+    # dispatch, Switch load-balance loss. "sigmoid" (DeepSeek-V3): top-k
+    # of sigmoid scores plus a selection bias, gates the selected scores
+    # normalised to sum 1 times ``routed_scale``, dropless dispatch, a
+    # sequence-wise balance loss over the real tokens.
+    router: str = "softmax"
+    routed_scale: float = 1.0
+    moe_d_ff: int = 0              # routed expert width (0: d_ff)
+    n_shared_experts: int = 0      # one SwiGLU of n * moe_d_ff, every token
+    n_dense_layers: int = 0        # leading layers with a dense d_ff FFN
+    # the routed experts this chip holds: [expert_offset, expert_offset
+    # + n_experts_held) of the router's n_experts (0 held: all of them)
+    expert_offset: int = 0
+    n_experts_held: int = 0
+    # multi-head latent attention (MLA) when kv_lora_rank > 0: a full q
+    # projection to n_heads x (nope + rope) dims, a kv_lora_rank latent
+    # plus one rope key shared by the heads, values v_head_dim wide
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # gemma-2 features
     sliding_window: Optional[int] = None   # local attention window
     local_global_alternating: bool = False
@@ -150,30 +171,47 @@ class TransformerConfig:
         return self.n_experts > 0
 
     @property
-    def n_params(self) -> int:
-        """Approximate parameter count (embeddings + trunk + head)."""
-        d, f, L, V = self.d_model, self.d_ff, self.n_layers, self.vocab_size
-        attn = d * self.n_heads * self.d_head * 2 \
-            + d * self.n_kv_heads * self.d_head * 2
-        if self.is_moe:
-            mlp = self.n_experts * 3 * d * f + d * self.n_experts
-        else:
-            mlp = 3 * d * f
-        trunk = L * (attn + mlp + 2 * d)
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    def _attn_params(self) -> int:
+        d, H = self.d_model, self.n_heads
+        if self.is_mla:
+            r, dn = self.kv_lora_rank, self.qk_nope_head_dim
+            dr, dv = self.qk_rope_head_dim, self.v_head_dim
+            return (d * H * (dn + dr) + d * (r + dr) + r
+                    + r * H * (dn + dv) + H * dv * d)
+        return d * H * self.d_head * 2 + d * self.n_kv_heads * self.d_head * 2
+
+    def _params(self, experts: int) -> int:
+        d, L, V = self.d_model, self.n_layers, self.vocab_size
+        moe_layers = L - self.n_dense_layers if self.is_moe else 0
+        ffn = (L - moe_layers) * 3 * d * self.d_ff
+        if moe_layers:
+            fe = self.expert_width
+            ffn += moe_layers * (experts * 3 * d * fe + d * self.n_experts
+                                 + self.n_shared_experts * 3 * d * fe)
         embed = V * d * (1 if self.tie_embeddings else 2)
-        return trunk + embed
+        return L * (self._attn_params() + 2 * d) + ffn + embed
+
+    @property
+    def n_params(self) -> int:
+        """Approximate parameter count (embeddings + trunk + head), at
+        the routed experts this chip holds."""
+        return self._params(self.experts_held)
 
     @property
     def n_active_params(self) -> int:
         """Active parameters per token (MoE counts top_k experts)."""
-        if not self.is_moe:
-            return self.n_params
-        d, f, L = self.d_model, self.d_ff, self.n_layers
-        attn = d * self.n_heads * self.d_head * 2 \
-            + d * self.n_kv_heads * self.d_head * 2
-        mlp = self.top_k * 3 * d * f + d * self.n_experts
-        embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        return L * (attn + mlp + 2 * d) + embed
+        return self._params(self.top_k) if self.is_moe else self.n_params
 
 
 @dataclasses.dataclass(frozen=True)

@@ -81,6 +81,8 @@ def transformer_param_specs(cfg: TransformerConfig, mesh: Mesh
     # -46% per-layer wire for +26% per-device flops on llama train
     # (EXPERIMENTS.md §Perf C.6).
     kv_aligned = cfg.n_kv_heads % mesh.shape[m] == 0
+    if cfg.router == "sigmoid":
+        return _latent_moe_specs(cfg, mesh, tp)
     attn = {
         "wq": tp(_divisible(cfg.n_heads * cfg.d_head, mesh, m),
                  P(None, None, m), P(None, None, None)),
@@ -126,6 +128,46 @@ def transformer_param_specs(cfg: TransformerConfig, mesh: Mesh
             "E": P(m, None) if vocab_ok else P(None, None),
             "b": P(m) if vocab_ok else P(None),
         }
+    return specs
+
+
+def _latent_moe_specs(cfg: TransformerConfig, mesh: Mesh, tp
+                      ) -> Dict[str, Any]:
+    """Specs for ``models.transformer._init_latent_moe``: MLA's head
+    projections over heads (the latent and its norm replicated), the
+    dense layers' FFN and the shared experts over their width, the
+    routed experts over ``model`` (EP), router and bias replicated."""
+    m = "model"
+    heads = _divisible(cfg.n_heads, mesh, m)
+    rep2, rep3 = P(None, None), P(None, None, None)
+    attn = {"wq": tp(heads, P(None, None, m), rep3),
+            "wkv_a": rep3, "kv_norm": rep2,
+            "wkv_b": tp(heads, P(None, None, m), rep3),
+            "wo": tp(heads, P(None, m, None), rep3)}
+
+    def ffn(width):
+        ok = _divisible(width, mesh, m)
+        return {"w_gate": tp(ok, P(None, None, m), rep3),
+                "w_up": tp(ok, P(None, None, m), rep3),
+                "w_down": tp(ok, P(None, m, None), rep3)}
+
+    experts = tp(_divisible(cfg.experts_held, mesh, m),
+                 P(None, m, None, None), P(None, None, None, None))
+    group = lambda mlp: {"attn": attn, "mlp": mlp, "ln1": rep2, "ln2": rep2}
+    vocab_ok = _divisible(cfg.vocab_size, mesh, m)
+    specs: Dict[str, Any] = {
+        "embed": P(m, None) if vocab_ok else rep2,
+        "layers": group({"router": rep3, "router_bias": rep2,
+                         "w_gate": experts, "w_up": experts,
+                         "w_down": experts,
+                         "shared": ffn(cfg.n_shared_experts
+                                       * cfg.expert_width)}),
+        "final_norm": P(None),
+        "lm_head": {"E": P(m, None) if vocab_ok else rep2,
+                    "b": P(m) if vocab_ok else P(None)},
+    }
+    if cfg.n_dense_layers:
+        specs["dense_layers"] = group(ffn(cfg.d_ff))
     return specs
 
 

@@ -58,9 +58,16 @@ PyTree = Any
 def _moe_shard(cfg: TransformerConfig, mesh: Optional[Mesh]):
     if mesh is None or not cfg.is_moe:
         return None
-    if cfg.n_experts % mesh.shape["model"] != 0:
+    if cfg.experts_held % mesh.shape["model"] != 0:
         return None
     return (batch_axes(mesh), "model")
+
+
+def _held_fixed(path) -> bool:
+    """A leaf that steers expert selection only (``router_bias``): no
+    gradient reaches it, and the train step leaves it out of AdamW's
+    update, decay included."""
+    return getattr(path[-1], "key", None) == "router_bias"
 
 
 def _encode_fn(cfg: TransformerConfig, mesh: Optional[Mesh],
@@ -167,6 +174,9 @@ def build_lsr_train_step(
         with jax.named_scope("optimizer"):
             updates, opt_state = opt.update(
                 grads, state["opt"], state["params"], state["step"])
+            updates = jax.tree_util.tree_map_with_path(
+                lambda path, u: jnp.zeros_like(u) if _held_fixed(path)
+                else u, updates)
             # cast at the ZeRO sharding, THEN all-gather in param dtype
             updates = jax.tree.map(lambda u, p: u.astype(p.dtype),
                                    updates, state["params"])

@@ -1,4 +1,5 @@
-"""Attention primitives: RoPE, chunked (online-softmax) attention, GQA.
+"""Attention primitives: RoPE, chunked (online-softmax) attention, GQA,
+multi-head latent attention (MLA).
 
 The training path uses a *chunked* attention (lax.scan over KV blocks
 with a running max/sum — the flash-attention recurrence expressed in
@@ -10,6 +11,11 @@ activation footprint that a fused TPU kernel would give.
 Masks are never materialized as ``(S, S)`` tensors: causal and
 sliding-window constraints are evaluated per KV chunk from iota
 comparisons, which XLA fuses into the score computation.
+
+MLA (DeepSeek-V2/V3, arXiv:2412.19437 section 2.1.1) projects each
+position to a small latent and one RoPE key that every head shares, and
+up-projects the latent to the heads' keys and values; queries and keys
+are ``qk_nope + qk_rope`` wide, values ``v_head_dim`` wide.
 """
 
 from __future__ import annotations
@@ -22,6 +28,12 @@ import jax.numpy as jnp
 
 Array = jax.Array
 NEG_INF = -1e30
+
+
+def rms_norm(x: Array, scale: Array, eps: float) -> Array:
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -70,7 +82,7 @@ def _chunk_mask(
 def chunked_attention(
     q: Array,            # (B, Sq, H, dh)
     k: Array,            # (B, Sk, KV, dh)
-    v: Array,            # (B, Sk, KV, dh)
+    v: Array,            # (B, Sk, KV, dv)
     *,
     q_positions: Array,  # (Sq,)
     k_positions: Array,  # (Sk,)
@@ -81,12 +93,12 @@ def chunked_attention(
     chunk_size: int = 512,
     unroll: int = 1,
 ) -> Array:
-    """Online-softmax attention over KV chunks; returns (B, Sq, H, dh).
+    """Online-softmax attention over KV chunks; returns (B, Sq, H, dv).
 
     ``unroll`` is for cost-probe lowering only (roofline.py): the KV
     chunk scan body must be replicated so cost_analysis counts it."""
     B, Sq, H, dh = q.shape
-    Sk, KV = k.shape[1], k.shape[2]
+    Sk, KV, dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV  # query groups per kv head
     scale = dh ** -0.5
 
@@ -101,7 +113,7 @@ def chunked_attention(
 
     qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, KV, G, dh)
     k_c = k.reshape(B, n_chunks, chunk_size, KV, dh)
-    v_c = v.reshape(B, n_chunks, chunk_size, KV, dh)
+    v_c = v.reshape(B, n_chunks, chunk_size, KV, dv)
     m_c = kv_mask.reshape(B, n_chunks, chunk_size)
     p_c = k_positions.reshape(n_chunks, chunk_size)
 
@@ -124,7 +136,7 @@ def chunked_attention(
         return (acc, new_max, row_sum), None
 
     init = (
-        jnp.zeros((B, Sq, KV, G, dh), jnp.float32),
+        jnp.zeros((B, Sq, KV, G, dv), jnp.float32),
         jnp.full((B, Sq, KV, G), NEG_INF, jnp.float32),
         jnp.zeros((B, Sq, KV, G), jnp.float32),
     )
@@ -135,7 +147,48 @@ def chunked_attention(
         unroll=unroll,
     )
     out = acc / jnp.maximum(row_sum[..., None], 1e-30)
-    return out.reshape(B, Sq, H, dh).astype(q.dtype)
+    return out.reshape(B, Sq, H, dv).astype(q.dtype)
+
+
+def mla_attention(
+    h: Array,            # (B, S, D) normed layer input, compute dtype
+    p,                   # wq, wkv_a, kv_norm, wkv_b, wo (one layer)
+    *,
+    n_heads: int,
+    kv_lora_rank: int,
+    qk_nope_head_dim: int,
+    qk_rope_head_dim: int,
+    v_head_dim: int,
+    positions: Array,    # (S,)
+    mask: Array,         # (B, S)
+    causal: bool,
+    rope_theta: float,
+    norm_eps: float,
+    chunk_size: int = 512,
+    unroll: int = 1,
+) -> Array:
+    """MLA with a full query projection (``q_lora_rank`` null); returns
+    the output projection (B, S, D). Each head's score is over
+    ``qk_nope + qk_rope`` dims, scaled by their count ** -0.5; RoPE
+    rotates the rope part of the queries and the shared rope key."""
+    B, S, _ = h.shape
+    H, r = n_heads, kv_lora_rank
+    dn, dr, dv = qk_nope_head_dim, qk_rope_head_dim, v_head_dim
+    c = h.dtype
+    pos2d = jnp.broadcast_to(positions[None], (B, S))
+    q = (h @ p["wq"].astype(c)).reshape(B, S, H, dn + dr)
+    q = jnp.concatenate(
+        [q[..., :dn], apply_rope(q[..., dn:], pos2d, rope_theta)], -1)
+    kv_a = h @ p["wkv_a"].astype(c)                          # (B, S, r+dr)
+    latent = rms_norm(kv_a[..., :r], p["kv_norm"], norm_eps)
+    k_rope = apply_rope(kv_a[..., None, r:], pos2d, rope_theta)
+    kv = (latent @ p["wkv_b"].astype(c)).reshape(B, S, H, dn + dv)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope, (B, S, H, dr))], -1)
+    out = chunked_attention(
+        q, k, kv[..., dn:], q_positions=positions, k_positions=positions,
+        kv_mask=mask, causal=causal, chunk_size=chunk_size, unroll=unroll)
+    return out.reshape(B, S, H * dv) @ p["wo"].astype(c)
 
 
 def decode_attention(

@@ -18,6 +18,14 @@ which XLA SPMD can partition: the expert dimension shards over the
 ``model`` mesh axis (expert parallelism), tokens over ``data``.
 
 Load-balancing auxiliary loss follows Switch Transformer (eq. 4-6).
+
+``sigmoid_moe_ffn`` is DeepSeek-V3's layer (arXiv:2412.19437, section
+2.1.2): sigmoid scores, top-k selection on score plus a per-expert bias
+that steers selection only, gates normalised over the selected scores,
+shared experts on every token, and a sequence-wise balance loss. Its
+dispatch is dropless: every assignment to a held expert is computed,
+by a grouped matmul (megablox) whose grid covers only the held
+experts' rows.
 """
 
 from __future__ import annotations
@@ -202,4 +210,162 @@ def init_moe_params(
             k3, (n_layers, n_experts, d_model, d_ff), dtype) * sc_in),
         "w_down": (jax.random.normal(
             k4, (n_layers, n_experts, d_ff, d_model), dtype) * sc_ff),
+    }
+
+
+# ---------------------------------------------------------------------------
+# sigmoid router, dropless held-expert dispatch (DeepSeek-V3)
+# ---------------------------------------------------------------------------
+
+def sigmoid_route(x: Array, router_w: Array, bias: Array, *, top_k: int,
+                  routed_scale: float) -> Tuple[Array, Array, Array]:
+    """(scores (T, E), expert ids (T, k), gates (T, k)) in float32.
+
+    The bias enters the selection only: a gate is the selected expert's
+    sigmoid score over the sum of the k selected scores, times
+    ``routed_scale``."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        router_w.astype(jnp.float32))
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    picked = jnp.take_along_axis(scores, idx, axis=1)
+    gates = picked / jnp.sum(picked, axis=1, keepdims=True) * routed_scale
+    return scores, idx, gates
+
+
+def sequence_balance_loss(scores: Array, idx: Array, mask: Array) -> Array:
+    """DeepSeek-V3's sequence-wise balance loss (eq. 17-20) without its
+    weight: per sequence, sum_i f_i P_i over the real tokens, with f_i
+    = E / (k n) times the tokens that selected expert i and P_i the mean
+    of expert i's score normalised over all experts; the mean over
+    sequences. ``scores`` (B, S, E), ``idx`` (B, S, k), ``mask`` (B, S)."""
+    E, k = scores.shape[-1], idx.shape[-1]
+    real = (mask > 0).astype(jnp.float32)
+    n = jnp.maximum(jnp.sum(real, axis=1), 1.0)                  # (B,)
+    picks = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32), axis=2)
+    f = jnp.einsum("bse,bs->be", picks, real) * (E / k) / n[:, None]
+    norm = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    P = jnp.einsum("bse,bs->be", norm, real) / n[:, None]
+    return jnp.mean(jnp.sum(jax.lax.stop_gradient(f) * P, axis=1))
+
+
+@jax.custom_vjp
+def _permute_rows(x: Array, order: Array, inverse: Array) -> Array:
+    """``x[order]`` for a permutation, whose gradient gathers by the
+    inverse permutation (no scatter)."""
+    return jnp.take(x, order, axis=0)
+
+
+def _permute_fwd(x, order, inverse):
+    return jnp.take(x, order, axis=0), (order, inverse)
+
+
+def _permute_bwd(res, g):
+    order, inverse = res
+    return jnp.take(g, inverse, axis=0), None, None
+
+
+_permute_rows.defvjp(_permute_fwd, _permute_bwd)
+
+# rows of the grouped matmul's tiles; a group's rows round up to whole
+# tiles, so a smaller tile wastes less on the held experts' boundaries
+ROW_TILE = 256
+
+
+def _gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """Megablox tiles: ``ROW_TILE`` rows; the contraction and output
+    widths in 512s where they divide, else whole (an expert width of
+    1408 = 11 x 128). About 6-10 MiB of VMEM at d_model 2048."""
+    return (ROW_TILE, 512 if k % 512 == 0 else k, 512 if n % 512 == 0 else n)
+
+
+def _grouped(x: Array, w: Array, sizes: Array, offset: Array,
+             interpret: bool) -> Array:
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    return gmm(x, w.astype(x.dtype), sizes, x.dtype, _gmm_tiling, offset,
+               None, False, interpret)
+
+
+def held_experts(x: Array, idx: Array, gates: Array, real: Array,
+                 w_gate: Array, w_up: Array, w_down: Array, *,
+                 n_experts: int, offset, interpret: bool) -> Array:
+    """The held experts' part of the routed output, dropless.
+
+    ``x`` (T, D); ``idx``/``gates`` (T, k) over all ``n_experts``;
+    ``real`` (T,) bool: pad tokens are not routed. ``w_*`` hold the
+    experts ``[offset, offset + w_gate.shape[0])``. Every (token, slot)
+    assignment is sorted by expert (pads after every expert), and the
+    grouped matmul computes only the held experts' rows and zeroes the
+    rest, so no assignment is dropped whatever the imbalance."""
+    T, D = x.shape
+    k = idx.shape[1]
+    key = jnp.where(real[:, None], idx, n_experts).reshape(-1)    # (T*k,)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32))
+    sizes = jnp.bincount(key, length=n_experts + 1).astype(jnp.int32)
+    M = T * k + (-T * k) % ROW_TILE
+    rows = _permute_rows(jnp.repeat(x, k, axis=0), order, inverse)
+    rows = jnp.pad(rows, ((0, M - T * k), (0, 0)))
+    sizes = sizes.at[n_experts].add(M - T * k)
+    offset = jnp.asarray(offset, jnp.int32)
+    g = _grouped(rows, w_gate, sizes, offset, interpret)
+    u = _grouped(rows, w_up, sizes, offset, interpret)
+    y = _grouped(jax.nn.silu(g) * u, w_down, sizes, offset, interpret)
+    y = _permute_rows(y[:T * k], inverse, order).reshape(T, k, D)
+    w = jnp.where(real[:, None], gates, 0.0).astype(y.dtype)
+    return jnp.einsum("tkd,tk->td", y, w)
+
+
+def sigmoid_moe_ffn(x: Array, mask: Array, p: Dict[str, Array], *,
+                    top_k: int, routed_scale: float, n_experts: int,
+                    offset, interpret: bool) -> Tuple[Array, Array]:
+    """DeepSeek-V3's routed experts on ``x`` (B, S, D) with ``mask``
+    (B, S): routed over all ``n_experts``, the held experts' part of
+    their output. Returns (out (B, S, D), the sequence-wise balance
+    loss). The shared experts are ``shared_experts``."""
+    B, S, D = x.shape
+    flat = x.reshape(B * S, D)
+    scores, idx, gates = sigmoid_route(flat, p["router"], p["router_bias"],
+                                       top_k=top_k,
+                                       routed_scale=routed_scale)
+    out = held_experts(flat, idx, gates, mask.reshape(-1) > 0,
+                       p["w_gate"], p["w_up"], p["w_down"],
+                       n_experts=n_experts, offset=offset,
+                       interpret=interpret)
+    aux = sequence_balance_loss(scores.reshape(B, S, -1),
+                                idx.reshape(B, S, -1), mask)
+    return out.reshape(B, S, D), aux
+
+
+def shared_experts(x: Array, p: Dict[str, Array]) -> Array:
+    """The shared experts, one SwiGLU of their summed width, on every
+    token."""
+    c = x.dtype
+    return (jax.nn.silu(x @ p["w_gate"].astype(c))
+            * (x @ p["w_up"].astype(c))) @ p["w_down"].astype(c)
+
+
+def init_sigmoid_moe_params(key: jax.Array, n_layers: int, d_model: int,
+                            d_expert: int, n_experts: int, n_held: int,
+                            n_shared: int, dtype=jnp.float32
+                            ) -> Dict[str, Array]:
+    """Router over all experts, a selection bias drawn from the key
+    (held fixed: no gradient reaches it, and the train step leaves it
+    out of AdamW), the held experts and the shared expert."""
+    ks = jax.random.split(key, 8)
+    L, D, F, Fs = n_layers, d_model, d_expert, n_shared * d_expert
+    n = lambda k, shape, fan_in: (jax.random.normal(k, shape, dtype)
+                                  * fan_in ** -0.5)
+    return {
+        "router": n(ks[0], (L, D, n_experts), D),
+        "router_bias": jax.random.normal(ks[1], (L, n_experts), dtype)
+        * 1e-2,
+        "w_gate": n(ks[2], (L, n_held, D, F), D),
+        "w_up": n(ks[3], (L, n_held, D, F), D),
+        "w_down": n(ks[4], (L, n_held, F, D), F),
+        "shared": {"w_gate": n(ks[5], (L, D, Fs), D),
+                   "w_up": n(ks[6], (L, D, Fs), D),
+                   "w_down": n(ks[7], (L, Fs, D), Fs)},
     }

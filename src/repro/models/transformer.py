@@ -5,13 +5,19 @@ SPLADE encoders:
 
 * dense GQA decoders (llama3.2-3b, phi3-mini),
 * local/global alternating attention + logit softcaps (gemma2-27b),
-* MoE trunks (moonshot-v1-16b-a3b: 64e top-6; phi3.5-moe: 16e top-2),
+* MoE trunks (phi3.5-moe: 16e top-2, softmax router),
+* DeepSeek-V3-style trunks (moonshot-v1-16b-a3b): multi-head latent
+  attention, a leading dense layer, then sigmoid-routed experts (64,
+  top-6) with two shared experts,
 * bidirectional encoders for SPLADE (bert / xlm-roberta backbones).
 
 Layers are *stacked* (every leaf carries a leading ``n_layers`` dim)
 and applied with ``lax.scan`` + optional ``jax.checkpoint`` so that the
 HLO stays compact for 512-device SPMD compilation and activation
-memory stays O(sqrt)-ish under remat.
+memory stays O(sqrt)-ish under remat. A config with leading dense
+layers (``n_dense_layers``) stacks them apart, under
+``params["dense_layers"]``: their FFN has other leaves than the MoE
+layers', so each group is its own scan.
 
 Heads:
 * ``lsr_encode``     — backbone + **Sparton head** (the paper): returns
@@ -29,12 +35,54 @@ import jax.numpy as jnp
 
 from repro.configs.base import TransformerConfig
 from repro.models.attention import (apply_rope, chunked_attention,
-                                    decode_attention)
-from repro.models.moe import (init_moe_params, moe_ffn,
-                             moe_ffn_local_experts)
+                                    decode_attention, mla_attention,
+                                    rms_norm)
+from repro.models.moe import (init_moe_params, init_sigmoid_moe_params,
+                             moe_ffn, moe_ffn_local_experts,
+                             shared_experts, sigmoid_moe_ffn)
 
 Array = jax.Array
 MoeShard = Optional[Tuple[Tuple[str, ...], str]]  # (token_axes, expert_axis)
+
+
+def _sigmoid_moe(x: Array, mask: Array, mlp: Params, cfg: TransformerConfig,
+                 moe_shard: MoeShard) -> Tuple[Array, Array]:
+    """DeepSeek-V3 MoE FFN on (B, S, D): the held routed experts plus
+    the shared experts. On a mesh each expert shard holds its slice of
+    the held experts (offset: ``expert_offset`` plus the shard's index
+    times its count) and the routed parts are summed over the expert
+    axis."""
+    from repro.kernels._common import interpret_mode
+
+    routed = functools.partial(
+        sigmoid_moe_ffn, top_k=cfg.top_k, routed_scale=cfg.routed_scale,
+        n_experts=cfg.n_experts, interpret=interpret_mode())
+    if moe_shard is None:
+        out, aux = routed(x, mask, mlp, offset=cfg.expert_offset)
+    else:
+        token_axes, expert_axis = moe_shard
+        from jax.sharding import PartitionSpec as P
+
+        def body(x, mask, mlp):
+            held = mlp["w_gate"].shape[0]
+            offset = cfg.expert_offset + jax.lax.axis_index(expert_axis) * held
+            out, aux = routed(x, mask, mlp, offset=offset)
+            if token_axes:
+                aux = jax.lax.pmean(aux, token_axes)
+            return jax.lax.psum(out, expert_axis), aux
+
+        experts = P(expert_axis, None, None)
+        spec = {"router": P(None, None), "router_bias": P(None),
+                "w_gate": experts, "w_up": experts, "w_down": experts}
+        routed_mlp = {k: mlp[k] for k in spec}
+        out, aux = jax.shard_map(
+            body, mesh=None,
+            in_specs=(P(token_axes, None, None), P(token_axes, None), spec),
+            out_specs=(P(token_axes, None, None), P()),
+            # the grouped matmul's Pallas call declares no varying axes
+            check_vma=False,
+        )(x, mask, routed_mlp)
+    return out + shared_experts(x, mlp["shared"]), aux
 
 
 def _apply_moe(x2d: Array, mlp: Params, cfg: TransformerConfig,
@@ -66,7 +114,59 @@ Params = Dict[str, Any]
 # init
 # ---------------------------------------------------------------------------
 
+def _init_mla(key: jax.Array, cfg: TransformerConfig, L: int,
+              dtype) -> Params:
+    D, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    n = lambda k, shape, fan_in: (jax.random.normal(k, shape, dtype)
+                                  * fan_in ** -0.5)
+    return {"wq": n(ks[0], (L, D, H * (dn + dr)), D),
+            "wkv_a": n(ks[1], (L, D, r + dr), D),
+            "kv_norm": jnp.ones((L, r), dtype),
+            "wkv_b": n(ks[2], (L, r, H * (dn + dv)), r),
+            "wo": n(ks[3], (L, H * dv, D), H * dv)}
+
+
+def _init_latent_moe(key: jax.Array, cfg: TransformerConfig) -> Params:
+    """The DeepSeek-V3 layout: MLA in every layer; ``n_dense_layers``
+    dense SwiGLU layers of ``d_ff``, then sigmoid-routed MoE layers with
+    the held experts and the shared experts."""
+    if not cfg.is_mla:
+        raise ValueError("the sigmoid-routed trunk has latent attention "
+                         "(kv_lora_rank > 0)")
+    dtype = jnp.dtype(cfg.param_dtype)
+    D, V, F = cfg.d_model, cfg.vocab_size, cfg.d_ff
+    Ld = cfg.n_dense_layers
+    Lm = cfg.n_layers - Ld
+    ks = jax.random.split(key, 9)
+    n = lambda k, shape, fan_in: (jax.random.normal(k, shape, dtype)
+                                  * fan_in ** -0.5)
+    group = lambda L, attn, mlp: {"attn": attn, "mlp": mlp,
+                                  "ln1": jnp.ones((L, D), dtype),
+                                  "ln2": jnp.ones((L, D), dtype)}
+    params: Params = {
+        "embed": n(ks[0], (V, D), D),
+        "layers": group(Lm, _init_mla(ks[1], cfg, Lm, dtype),
+                        init_sigmoid_moe_params(
+                            ks[2], Lm, D, cfg.expert_width, cfg.n_experts,
+                            cfg.experts_held, cfg.n_shared_experts, dtype)),
+        "final_norm": jnp.ones((D,), dtype),
+        "lm_head": {"E": n(ks[3], (V, D), D),
+                    "b": jnp.zeros((V,), jnp.float32)},
+    }
+    if Ld:
+        params["dense_layers"] = group(
+            Ld, _init_mla(ks[4], cfg, Ld, dtype),
+            {"w_gate": n(ks[5], (Ld, D, F), D),
+             "w_up": n(ks[6], (Ld, D, F), D),
+             "w_down": n(ks[7], (Ld, F, D), F)})
+    return params
+
+
 def init_params(key: jax.Array, cfg: TransformerConfig) -> Params:
+    if cfg.router == "sigmoid":
+        return _init_latent_moe(key, cfg)
     dtype = jnp.dtype(cfg.param_dtype)
     L, D, V = cfg.n_layers, cfg.d_model, cfg.vocab_size
     H, KV, dh, F = cfg.n_heads, cfg.n_kv_heads, cfg.d_head, cfg.d_ff
@@ -118,12 +218,6 @@ def head_weights(params: Params, cfg: TransformerConfig):
 # building blocks
 # ---------------------------------------------------------------------------
 
-def rms_norm(x: Array, scale: Array, eps: float) -> Array:
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps) * scale).astype(x.dtype)
-
-
 def _layer(
     x: Array,                 # (B, S, D)
     lp: Params,               # one layer's params (leading L dim removed)
@@ -140,24 +234,42 @@ def _layer(
     cdtype = jnp.dtype(cfg.compute_dtype)
 
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-    q = (h @ lp["attn"]["wq"].astype(cdtype)).reshape(B, S, H, dh)
-    k = (h @ lp["attn"]["wk"].astype(cdtype)).reshape(B, S, KV, dh)
-    v = (h @ lp["attn"]["wv"].astype(cdtype)).reshape(B, S, KV, dh)
-    pos2d = jnp.broadcast_to(positions[None], (B, S))
-    q = apply_rope(q, pos2d, cfg.rope_theta)
-    k = apply_rope(k, pos2d, cfg.rope_theta)
-    attn_out = chunked_attention(
-        q, k, v,
-        q_positions=positions, k_positions=positions, kv_mask=mask,
-        causal=causal, window=window,
-        logit_softcap=cfg.attn_logit_softcap,
-        chunk_size=cfg.attn_chunk,
-        unroll=cfg.attn_unroll,
-    )
-    x = x + attn_out.reshape(B, S, H * dh) @ lp["attn"]["wo"].astype(cdtype)
+    if cfg.is_mla:
+        with jax.named_scope("mla"):
+            x = x + mla_attention(
+                h, lp["attn"], n_heads=H, kv_lora_rank=cfg.kv_lora_rank,
+                qk_nope_head_dim=cfg.qk_nope_head_dim,
+                qk_rope_head_dim=cfg.qk_rope_head_dim,
+                v_head_dim=cfg.v_head_dim, positions=positions, mask=mask,
+                causal=causal, rope_theta=cfg.rope_theta,
+                norm_eps=cfg.norm_eps, chunk_size=cfg.attn_chunk,
+                unroll=cfg.attn_unroll)
+    else:
+        q = (h @ lp["attn"]["wq"].astype(cdtype)).reshape(B, S, H, dh)
+        k = (h @ lp["attn"]["wk"].astype(cdtype)).reshape(B, S, KV, dh)
+        v = (h @ lp["attn"]["wv"].astype(cdtype)).reshape(B, S, KV, dh)
+        pos2d = jnp.broadcast_to(positions[None], (B, S))
+        q = apply_rope(q, pos2d, cfg.rope_theta)
+        k = apply_rope(k, pos2d, cfg.rope_theta)
+        attn_out = chunked_attention(
+            q, k, v,
+            q_positions=positions, k_positions=positions, kv_mask=mask,
+            causal=causal, window=window,
+            logit_softcap=cfg.attn_logit_softcap,
+            chunk_size=cfg.attn_chunk,
+            unroll=cfg.attn_unroll,
+        )
+        x = x + (attn_out.reshape(B, S, H * dh)
+                 @ lp["attn"]["wo"].astype(cdtype))
 
     h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if cfg.is_moe:
+    # the layer's FFN kind is in its leaves: a leading dense layer of a
+    # MoE model has none of the experts'
+    if "router_bias" in lp["mlp"]:
+        with jax.named_scope("moe"):
+            out, aux = _sigmoid_moe(h, mask, lp["mlp"], cfg, moe_shard)
+        x = x + out
+    elif "router" in lp["mlp"]:
         out, aux = _apply_moe(h.reshape(B * S, D), lp["mlp"], cfg,
                               moe_shard)
         x = x + out.reshape(B, S, D)
@@ -226,10 +338,17 @@ def forward_hidden(
     if cfg.remat:
         body = jax.checkpoint(
             scan_body, policy=jax.checkpoint_policies.nothing_saveable)
-    layer_ids = jnp.arange(cfg.n_layers, dtype=jnp.int32)
-    (x, aux), _ = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)),
-        (params["layers"], layer_ids), unroll=unroll)
+    carry, first = (x, jnp.zeros((), jnp.float32)), 0
+    # leading dense layers (if any), then the rest: one scan each
+    for group in ("dense_layers", "layers"):
+        if group not in params:
+            continue
+        n = jax.tree.leaves(params[group])[0].shape[0]
+        layer_ids = jnp.arange(first, first + n, dtype=jnp.int32)
+        carry, _ = jax.lax.scan(body, carry, (params[group], layer_ids),
+                                unroll=unroll)
+        first += n
+    x, aux = carry
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux
 
@@ -306,6 +425,10 @@ def decode_step(
     cache allocation (measured ~2.7x cache bytes in temps on the
     decode_32k dry-run cell).
     """
+    if cfg.is_mla:
+        raise NotImplementedError(
+            "decode caches k and v per head; latent attention (MLA) has "
+            "no latent-cache decode path")
     B = tokens.shape[0]
     cdtype = jnp.dtype(cfg.compute_dtype)
     H, KV, dh, D = cfg.n_heads, cfg.n_kv_heads, cfg.d_head, cfg.d_model

@@ -6,8 +6,11 @@ kernel may use. These tests compile, without a chip, for a described
 ``v5e:2x2`` topology:
 
 * the Sparton head, forward and forward+backward, at D=768 and the two
-  vocabularies of the paper's models (V=30522, V=250002), with the
+  vocabularies of the paper's models (V=30522, V=250002), and at
+  Moonlight's D=2048 over its 20480-row vocabulary slice, with the
   blocks the autotuner picks from a cold cache;
+* the MoE's grouped matmuls (megablox), forward and backward, at
+  Moonlight's widths (D=2048, expert width 1408, 8 of 64 experts held);
 * the fused impact scorer, f32 and u4 variants, at a serving window.
 
 The topology is described in a fixture, never while a module is
@@ -75,6 +78,17 @@ def _sds(shape, dtype, sharding):
 @pytest.mark.parametrize("V", [30522, 250002])
 @pytest.mark.parametrize("backward", [False, True])
 def test_sparton_head_compiles_for_v5e(one_chip, V, backward):
+    _head_compiles(one_chip, V, D, backward)
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_sparton_head_compiles_at_moonlight_width(one_chip, backward):
+    """D=2048 over the 20480-row vocabulary slice: the dH kernel's
+    (block_b, block_s, D) scratch is 2.7x that at D=768."""
+    _head_compiles(one_chip, 20480, 2048, backward)
+
+
+def _head_compiles(one_chip, V, D, backward):
     def fwd(H, E, b, mask):
         return sparton_head(H, E, b, mask, out_dtype=jnp.float32)
 
@@ -109,3 +123,31 @@ def test_fused_impact_compiles_for_v5e(one_chip, variant):
         args = (win, win, col_i, col_i, col_f, col_f, col_f)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("backward", [False, True])
+def test_held_experts_compile_for_v5e(one_chip, backward):
+    from repro.models.moe import held_experts
+
+    T, Dm, F, E, held, k = 2048, 2048, 1408, 64, 8, 6
+
+    def fwd(x, gates, idx, real, w_gate, w_up, w_down):
+        return held_experts(x, idx, gates, real, w_gate, w_up, w_down,
+                            n_experts=E, offset=0, interpret=False)
+
+    def loss(x, gates, *rest):
+        return jnp.sum(fwd(x, gates, *rest).astype(jnp.float32))
+
+    fn = jax.grad(loss, (0, 1, 4, 5, 6)) if backward else fwd
+    compiled = jax.jit(fn).lower(
+        _sds((T, Dm), jnp.bfloat16, one_chip),
+        _sds((T, k), jnp.float32, one_chip),
+        _sds((T, k), jnp.int32, one_chip),
+        _sds((T,), jnp.bool_, one_chip),
+        _sds((held, Dm, F), jnp.float32, one_chip),
+        _sds((held, Dm, F), jnp.float32, one_chip),
+        _sds((held, F, Dm), jnp.float32, one_chip)).compile()
+    # gate, up and down; with the backward, each one's input and weight
+    # gradients too
+    assert compiled.as_text().count("tpu_custom_call") >= (
+        9 if backward else 3)
