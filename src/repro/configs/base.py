@@ -116,7 +116,7 @@ class TransformerConfig:
     rep_threshold: Optional[float] = None
     rep_max_nnz: int = 256         # threshold-only static slot budget
     attn_unroll: int = 1           # KV-chunk scan unroll (cost probes)
-    attn_chunk: int = 512          # KV chunk size (online softmax)
+    attn_chunk: int = 512          # KV chunk of chunked_attention
 
     def head_blocks(self, batch: int, seq_len: int,
                     dtype: Optional[str] = None

@@ -1,6 +1,8 @@
 """Pallas TPU kernels for the framework's compute hot-spots.
 
 * ``sparton`` / ``sparton_bwd`` — the paper's fused LM head (fwd + bwd).
+* ``attention`` — the backbone's fused attention (fwd + bwd), which
+  keeps the scores on chip and skips blocks past each row's extent.
 * ``topk_score`` — beyond-paper transfer: fused streaming top-k
   retrieval scoring (never materializes the (B, N) score matrix).
 * ``ops`` — jit'd differentiable wrappers (``custom_vjp``).

@@ -91,7 +91,7 @@ def _encode_fn(cfg: TransformerConfig, mesh: Optional[Mesh],
 
     def encode(params, tokens, mask):
         Hs, aux = tfm.forward_hidden(params, cfg, tokens, mask,
-                                     moe_shard=moe_shard,
+                                     moe_shard=moe_shard, mesh=mesh,
                                      unroll=layer_unroll)
         E, b = tfm.head_weights(params, cfg)
         y = head(Hs, E.astype(Hs.dtype), b, mask)

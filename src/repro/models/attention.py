@@ -1,12 +1,16 @@
 """Attention primitives: RoPE, chunked (online-softmax) attention, GQA,
 multi-head latent attention (MLA).
 
-The training path uses a *chunked* attention (lax.scan over KV blocks
-with a running max/sum — the flash-attention recurrence expressed in
-XLA) so that the ``(B, H, S, S)`` score tensor is never materialized.
-This keeps the multi-pod dry-run compilable on the CPU backend (Pallas
-TPU attention kernels cannot lower there) while preserving the O(S)
-activation footprint that a fused TPU kernel would give.
+``attention`` is what the layers call. On a TPU, for attention with
+neither a sliding window nor a logit softcap and outside the cost
+probes, it runs the fused Pallas kernel (``kernels/attention.py``),
+which keeps the scores on chip and visits only the blocks inside each
+row's extent. Everywhere else (the CPU backend, where the tests and the
+multi-pod dry-run compile and Pallas TPU kernels cannot lower; softcaps;
+sliding windows; the cost probes) it runs ``chunked_attention``: a
+lax.scan over KV chunks with a running max/sum, the flash-attention
+recurrence expressed in XLA, which holds one ``(B, Sq, H, chunk)`` f32
+score block at a time.
 
 Masks are never materialized as ``(S, S)`` tensors: causal and
 sliding-window constraints are evaluated per KV chunk from iota
@@ -25,6 +29,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
+
+from repro.kernels._common import interpret_mode
+from repro.kernels.attention import fused_attention
 
 Array = jax.Array
 NEG_INF = -1e30
@@ -150,6 +158,59 @@ def chunked_attention(
     return out.reshape(B, Sq, H, dv).astype(q.dtype)
 
 
+def _fused_applies(window: Optional[int], logit_softcap: Optional[float],
+                   unroll: int) -> bool:
+    """Whether ``attention`` runs the fused kernel: on a TPU, with no
+    sliding window, no softcap and no cost-probe unroll."""
+    return (window is None and logit_softcap is None and unroll == 1
+            and not interpret_mode())
+
+
+def attention(
+    q: Array,            # (B, S, H, dqk)
+    k: Array,            # (B, S, KV, dqk)
+    v: Array,            # (B, S, KV, dv)
+    *,
+    positions: Array,    # (S,) 0 .. S-1
+    kv_mask: Array,      # (B, S) 1 = valid
+    causal: bool,
+    window: Optional[int] = None,
+    logit_softcap: Optional[float] = None,
+    chunk_size: int = 512,
+    unroll: int = 1,
+    mesh: Optional[Mesh] = None,
+) -> Array:
+    """Self-attention over one sequence of positions; returns
+    (B, S, H, dv), under the named scope ``attention``. The fused kernel
+    leaves q blocks past a row's extent at 0; every real position reads
+    the same in both paths. ``mesh``: the mesh of a data-parallel step,
+    whose batch axes split the rows."""
+    with jax.named_scope("attention"):
+        if _fused_applies(window, logit_softcap, unroll):
+            return _fused(q, k, v, kv_mask, causal, mesh)
+        return chunked_attention(
+            q, k, v, q_positions=positions, k_positions=positions,
+            kv_mask=kv_mask, causal=causal, window=window,
+            logit_softcap=logit_softcap, chunk_size=chunk_size,
+            unroll=unroll)
+
+
+def _fused(q, k, v, kv_mask, causal, mesh):
+    """The kernel; on a mesh, inside a shard_map over the batch axes that
+    split the rows, since the partitioner cannot split a Mosaic kernel."""
+    def run(q, k, v, kv_mask):
+        return fused_attention(q, k, v, kv_mask, causal, interpret_mode())
+
+    if mesh is None:
+        return run(q, k, v, kv_mask)
+    from repro.launch.sharding import batch_axes_for
+
+    rows = P(batch_axes_for(mesh, q.shape[0]) or None)
+    # the kernel's call declares no varying axes
+    return jax.shard_map(run, mesh=mesh, in_specs=(rows,) * 4,
+                         out_specs=rows, check_vma=False)(q, k, v, kv_mask)
+
+
 def mla_attention(
     h: Array,            # (B, S, D) normed layer input, compute dtype
     p,                   # wq, wkv_a, kv_norm, wkv_b, wo (one layer)
@@ -166,6 +227,7 @@ def mla_attention(
     norm_eps: float,
     chunk_size: int = 512,
     unroll: int = 1,
+    mesh: Optional[Mesh] = None,
 ) -> Array:
     """MLA with a full query projection (``q_lora_rank`` null); returns
     the output projection (B, S, D). Each head's score is over
@@ -185,9 +247,9 @@ def mla_attention(
     kv = (latent @ p["wkv_b"].astype(c)).reshape(B, S, H, dn + dv)
     k = jnp.concatenate(
         [kv[..., :dn], jnp.broadcast_to(k_rope, (B, S, H, dr))], -1)
-    out = chunked_attention(
-        q, k, kv[..., dn:], q_positions=positions, k_positions=positions,
-        kv_mask=mask, causal=causal, chunk_size=chunk_size, unroll=unroll)
+    out = attention(q, k, kv[..., dn:], positions=positions, kv_mask=mask,
+                    causal=causal, chunk_size=chunk_size, unroll=unroll,
+                    mesh=mesh)
     return out.reshape(B, S, H * dv) @ p["wo"].astype(c)
 
 

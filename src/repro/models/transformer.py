@@ -32,9 +32,10 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
 from repro.configs.base import TransformerConfig
-from repro.models.attention import (apply_rope, chunked_attention,
+from repro.models.attention import (apply_rope, attention,
                                     decode_attention, mla_attention,
                                     rms_norm)
 from repro.models.moe import (init_moe_params, init_sigmoid_moe_params,
@@ -228,6 +229,7 @@ def _layer(
     causal: bool,
     window: Optional[int],
     moe_shard: MoeShard = None,
+    mesh: Optional[Mesh] = None,
 ) -> Tuple[Array, Array]:
     B, S, D = x.shape
     H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
@@ -243,7 +245,7 @@ def _layer(
                 v_head_dim=cfg.v_head_dim, positions=positions, mask=mask,
                 causal=causal, rope_theta=cfg.rope_theta,
                 norm_eps=cfg.norm_eps, chunk_size=cfg.attn_chunk,
-                unroll=cfg.attn_unroll)
+                unroll=cfg.attn_unroll, mesh=mesh)
     else:
         q = (h @ lp["attn"]["wq"].astype(cdtype)).reshape(B, S, H, dh)
         k = (h @ lp["attn"]["wk"].astype(cdtype)).reshape(B, S, KV, dh)
@@ -251,14 +253,10 @@ def _layer(
         pos2d = jnp.broadcast_to(positions[None], (B, S))
         q = apply_rope(q, pos2d, cfg.rope_theta)
         k = apply_rope(k, pos2d, cfg.rope_theta)
-        attn_out = chunked_attention(
-            q, k, v,
-            q_positions=positions, k_positions=positions, kv_mask=mask,
-            causal=causal, window=window,
-            logit_softcap=cfg.attn_logit_softcap,
-            chunk_size=cfg.attn_chunk,
-            unroll=cfg.attn_unroll,
-        )
+        attn_out = attention(
+            q, k, v, positions=positions, kv_mask=mask, causal=causal,
+            window=window, logit_softcap=cfg.attn_logit_softcap,
+            chunk_size=cfg.attn_chunk, unroll=cfg.attn_unroll, mesh=mesh)
         x = x + (attn_out.reshape(B, S, H * dh)
                  @ lp["attn"]["wo"].astype(cdtype))
 
@@ -296,13 +294,16 @@ def forward_hidden(
     *,
     causal: Optional[bool] = None,
     moe_shard: MoeShard = None,
+    mesh: Optional[Mesh] = None,
     unroll: int = 1,
 ) -> Tuple[Array, Array]:
     """Returns (H (B, S, D) in compute dtype, aux_loss scalar).
 
-    ``unroll``: lax.scan unroll factor over layers. The dry-run uses
-    full unroll so ``cost_analysis()`` counts every layer (a rolled
-    scan reports its body cost only once); runtime uses 1."""
+    ``mesh``: the mesh of a data-parallel step, whose batch axes split
+    the rows of the attention kernel. ``unroll``: lax.scan unroll factor
+    over layers. The dry-run uses full unroll so ``cost_analysis()``
+    counts every layer (a rolled scan reports its body cost only once);
+    runtime uses 1."""
     B, S = tokens.shape
     cdtype = jnp.dtype(cfg.compute_dtype)
     if mask is None:
@@ -320,7 +321,7 @@ def forward_hidden(
         def run(window):
             return _layer(x, lp, cfg, positions=positions, mask=mask,
                           causal=causal, window=window,
-                          moe_shard=moe_shard)
+                          moe_shard=moe_shard, mesh=mesh)
 
         if cfg.local_global_alternating and cfg.sliding_window:
             # even layers local (sliding window), odd layers global —

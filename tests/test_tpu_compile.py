@@ -11,7 +11,12 @@ kernel may use. These tests compile, without a chip, for a described
   blocks the autotuner picks from a cold cache;
 * the MoE's grouped matmuls (megablox), forward and backward, at
   Moonlight's widths (D=2048, expert width 1408, 8 of 64 experts held);
-* the fused impact scorer, f32 and u4 variants, at a serving window.
+* the fused impact scorer, f32 and u4 variants, at a serving window;
+* the backbone's fused attention, forward and backward, at the encoders'
+  widths (12 heads of 64), Moonlight's latent attention (16 heads,
+  q/k 192 and v 128 wide, causal) and a GQA decoder's (24 query heads
+  over 8 kv heads of 128), at documents' and queries' lengths; and
+  the instruction names and scopes its calls carry in a model's step.
 
 The topology is described in a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and the
@@ -27,6 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import autotune
+from repro.kernels.attention import fused_attention
 from repro.kernels.impact_score import (fused_impact_topk,
                                         fused_quantized_topk)
 from repro.kernels.ops import sparton_head
@@ -151,3 +157,107 @@ def test_held_experts_compile_for_v5e(one_chip, backward):
     # gradients too
     assert compiled.as_text().count("tpu_custom_call") >= (
         9 if backward else 3)
+
+
+# (H, KV, dqk, dv, causal)
+ATTENTION_WIDTHS = {"encoder": (12, 12, 64, 64, False),
+                    "mla": (16, 16, 192, 128, True),
+                    "gqa": (24, 8, 128, 128, True)}
+
+
+@pytest.mark.parametrize("width", list(ATTENTION_WIDTHS))
+@pytest.mark.parametrize("seq", [256, 32])
+@pytest.mark.parametrize("backward", [False, True])
+def test_fused_attention_compiles_for_v5e(one_chip, width, seq, backward):
+    H, KV, dqk, dv, causal = ATTENTION_WIDTHS[width]
+
+    def fwd(q, k, v, mask):
+        return fused_attention(q, k, v, mask, causal, False)
+
+    def loss(q, k, v, mask):
+        return jnp.sum(fwd(q, k, v, mask).astype(jnp.float32))
+
+    fn = jax.grad(loss, (0, 1, 2)) if backward else fwd
+    compiled = jax.jit(fn).lower(
+        _sds((B, seq, H, dqk), jnp.bfloat16, one_chip),
+        _sds((B, seq, KV, dqk), jnp.bfloat16, one_chip),
+        _sds((B, seq, KV, dv), jnp.bfloat16, one_chip),
+        _sds((B, seq), jnp.int32, one_chip)).compile()
+    # fwd alone, or fwd + dQ + dK/dV
+    assert compiled.as_text().count("tpu_custom_call") >= (
+        3 if backward else 1)
+
+
+@pytest.mark.parametrize("arch,scopes", [
+    ("splade_bert", ("backbone", "attention")),
+    ("moonshot_v1_16b", ("backbone", "mla", "attention"))])
+def test_fused_attention_calls_carry_the_model_scopes(one_chip, monkeypatch,
+                                                      arch, scopes):
+    """In a model's forward and backward on the chip every attention
+    call is the kernel, named ``attention_fwd``, ``attention_dq`` or
+    ``attention_dkv``, and its ``op_name`` holds the scopes that the
+    benchmark's readers take it under."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models import attention as attn
+    from repro.models import transformer as tfm
+
+    # the chip's answer, on a process whose backend is the CPU
+    monkeypatch.setattr(attn, "interpret_mode", lambda: False)
+    cfg = dataclasses.replace(get_config(arch).SMOKE, remat=True)
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda: tfm.init_params(jax.random.PRNGKey(0), cfg)))
+    toks = _sds((8, S), jnp.int32, one_chip)
+
+    def loss(p, t, m):
+        hidden, aux = tfm.forward_hidden(p, cfg, t, m)
+        return jnp.sum(hidden.astype(jnp.float32)) + aux
+
+    text = jax.jit(jax.grad(loss)).lower(params, toks, toks).compile() \
+        .as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "op_name" in line]
+    names = {re.match(r"\s*(?:ROOT\s+)?%?([a-z_]+)", c).group(1)
+             for c in calls}
+    assert names == {"attention_fwd", "attention_dq", "attention_dkv"}
+    for call in calls:
+        op_name = re.search(r'op_name="([^"]*)"', call).group(1)
+        assert all(f"/{s}/" in op_name or f"({s})" in op_name
+                   for s in scopes), op_name
+
+
+def test_data_parallel_step_runs_the_kernel_on_local_rows(topo, monkeypatch):
+    """A data-parallel train step on four chips: the partitioner cannot
+    split a Mosaic kernel, so the attention kernel runs in a shard_map,
+    on each chip's quarter of the rows."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.launch.steps import build_lsr_train_step, init_state
+    from repro.models import attention as attn
+
+    monkeypatch.setattr(attn, "interpret_mode", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+    cfg = dataclasses.replace(get_config("splade_bert").SMOKE,
+                              head_impl="jax")
+    rep, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    state = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, rep),
+        jax.eval_shape(lambda: init_state(
+            "splade_bert", jax.random.PRNGKey(0), smoke=True)[0]))
+    batch = {k: _sds((16, S), jnp.int32, rows)
+             for k in ("q_tokens", "q_mask", "d_tokens", "d_mask")}
+    step = jax.jit(build_lsr_train_step(cfg, mesh, n_pairs=16))
+    text = step.lower(state, batch).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in line
+               and "attention_fwd" in line]
+    assert kernels
+    # each chip's call takes its own 4 of the 16 rows
+    assert all("bf16[4,256," in line for line in kernels)
